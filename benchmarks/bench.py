@@ -170,6 +170,8 @@ def main() -> int:
     paths = []
 
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     train_metrics = _train_smoke(args)
     row = benchrow.bench_row(
         name="train_smoke", kind="train", metrics=train_metrics,

@@ -29,7 +29,8 @@ PAPER_MODELS = {
     "gpt-moe-52b": {"h": 1024, "k": 2},
     "swin-moe-l": {"h": 1536, "k": 2},
 }
-V5E = {"flops": hw.DEVICE_FLOPS, "b_inter": hw.ICI_BYTES_PER_S}
+_PEAKS = hw.peaks(hw.V5E)
+V5E = {"flops": _PEAKS.flops, "b_inter": _PEAKS.ici_bytes_per_s}
 
 
 def run(out_rows):

@@ -1,9 +1,3 @@
-"""LSH-MoE reproduction (arXiv 2411.08446) on JAX + Pallas.
-
-Importing the package pulls in the version-compat layer so API drift in
-the underlying JAX fails at import time (the CI smoke step), not deep in a
-test run.
-"""
-from repro import compat  # noqa: F401  (import-time version check)
+"""LSH-MoE reproduction (arXiv 2411.08446) on JAX 0.9 + Pallas."""
 
 __version__ = "0.1.0"

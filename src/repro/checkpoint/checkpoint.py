@@ -37,15 +37,9 @@ import jax.numpy as jnp
 import msgpack
 import numpy as np
 
-try:                                   # gated dep: zstd when available ...
-    import zstandard
-except ImportError:                    # ... stdlib zlib otherwise
-    zstandard = None
-import zlib
+import zstandard
 
 from repro.obs import events as obs_events
-
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
 
 
 class CheckpointError(RuntimeError):
@@ -59,19 +53,11 @@ class CheckpointCorruptError(CheckpointError):
 
 
 def _compress(raw: bytes) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=3).compress(raw)
-    return zlib.compress(raw, 3)
+    return zstandard.ZstdCompressor(level=3).compress(raw)
 
 
 def _decompress(buf: bytes) -> bytes:
-    if buf[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise RuntimeError(
-                "checkpoint is zstd-compressed but zstandard is not "
-                "installed on this host")
-        return zstandard.ZstdDecompressor().decompress(buf)
-    return zlib.decompress(buf)
+    return zstandard.ZstdDecompressor().decompress(buf)
 
 _KEY_SEP = "/"
 
@@ -125,8 +111,7 @@ def save_checkpoint(directory: str, step: int, tree, *,
         payload[key] = (arr.tobytes(), str(arr.dtype), list(arr.shape))
     proc = jax.process_index()
     raw = msgpack.packb(payload, use_bin_type=True)
-    ext = "zst" if zstandard is not None else "zlib"
-    shard_name = f"shard_{proc}.msgpack.{ext}"
+    shard_name = f"shard_{proc}.msgpack.zst"
     comp = _compress(raw)
     # integrity: digest of the on-disk bytes, verified by load_checkpoint
     manifest["digests"] = {shard_name: hashlib.sha256(comp).hexdigest()}
@@ -195,8 +180,6 @@ def _read_payload(path: str, manifest: Dict) -> Dict:
         try:
             raw = _decompress(comp)
             payload.update(msgpack.unpackb(raw, raw=False))
-        except RuntimeError:
-            raise                # zstd-missing environment error, not damage
         except Exception as e:
             raise CheckpointCorruptError(
                 f"{path}: shard {name} undecodable ({e!r})") from e

@@ -1,99 +1,24 @@
-"""Version-portable JAX surface (single choke point for API drift).
+"""The two JAX 0.9 entry points every mesh-aware call site goes through.
 
-The repo targets JAX 0.4.x (the pinned CI toolchain) through ≥0.6, which
-moved or renamed several APIs this codebase leans on:
-
-  shard_map   0.4.x: ``jax.experimental.shard_map.shard_map(..., check_rep=)``
-              ≥0.6:  ``jax.shard_map(..., check_vma=)``
-  set_mesh    0.4.x: absent — the nearest equivalent is entering the
-              ``Mesh`` context manager (legacy resource env)
-              0.5.x: ``jax.sharding.use_mesh``
-              ≥0.6:  ``jax.set_mesh``
-  tree utils  0.4.25+: ``jax.tree.map`` etc.; older/newer fall back to
-              ``jax.tree_util``
-
-Every ``shard_map`` / ``set_mesh`` call site in src/, tests/, benchmarks/
-and examples/ imports these wrappers instead of reaching into ``jax``
-directly, so the next rename is a one-file fix.  The tree wrappers are
-provided for the same reason but most code still uses ``jax.tree.*``
-(stable since 0.4.25) — adopt them here first if that surface moves again.
-Keyword names here are version-neutral on purpose (``check_replication``
-rather than ``check_rep``/``check_vma``).
+  shard_map   ``jax.shard_map`` with the replication check off by default:
+              every region here returns per-shard values whose replication
+              the checker cannot always prove (explicit collectives).
+  set_mesh    ``jax.set_mesh``, the ambient-mesh context manager.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-from typing import Any, Callable
+from typing import Callable
 
 import jax
-
-JAX_VERSION: tuple = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-
-# ------------------------------------------------------------- shard_map --
-
-if hasattr(jax, "shard_map"):                      # newer: top level
-    _shard_map_impl = jax.shard_map
-else:                                              # 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# The check_rep -> check_vma rename and the promotion to jax.shard_map
-# landed in different releases, so pick the kwarg from the resolved
-# function's own signature rather than its import location.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map_impl).parameters else "check_rep")
 
 
 def shard_map(f: Callable, mesh, in_specs, out_specs,
               check_replication: bool = False) -> Callable:
-    """``jax.shard_map`` across JAX versions.
-
-    ``check_replication`` maps to ``check_rep`` (0.4.x) or ``check_vma``
-    (≥0.6).  Default False: every region here returns per-shard values whose
-    replication the checker cannot always prove (explicit collectives).
-    """
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_CHECK_KW: check_replication})
+    """``jax.shard_map`` with ``check_vma=check_replication``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
-# -------------------------------------------------------------- set_mesh --
+set_mesh = jax.set_mesh
 
-if hasattr(jax, "set_mesh"):                       # ≥ 0.6
-    def set_mesh(mesh):
-        return jax.set_mesh(mesh)
-elif hasattr(jax.sharding, "use_mesh"):            # 0.5.x experimental
-    def set_mesh(mesh):
-        return jax.sharding.use_mesh(mesh)
-else:                                              # 0.4.x
-    @contextlib.contextmanager
-    def set_mesh(mesh):
-        """Entering the Mesh context is the 0.4.x ambient-mesh equivalent
-        (all our jit/shard_map calls also pass the mesh explicitly)."""
-        with mesh:
-            yield mesh
-
-
-# ------------------------------------------------------------ tree utils --
-
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-else:                                              # very old / renamed again
-    tree_map = jax.tree_util.tree_map
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_flatten = jax.tree_util.tree_flatten
-    tree_unflatten = jax.tree_util.tree_unflatten
-
-
-def default_backend() -> str:
-    """Platform name ("cpu" | "gpu" | "tpu") — stable across versions."""
-    return jax.default_backend()
-
-
-__all__ = ["JAX_VERSION", "shard_map", "set_mesh", "tree_map", "tree_leaves",
-           "tree_flatten", "tree_unflatten", "default_backend"]
+__all__ = ["shard_map", "set_mesh"]

@@ -1,4 +1,4 @@
-"""granite-moe-3b-a800m [moe] — hf:ibm-granite/granite-3.0-1b-a400m-base (hf).
+"""granite-moe-3b-a800m [moe] — hf:ibm-granite/granite-3.0-3b-a800m-base (hf).
 32L d_model=1536 24H (GQA kv=8) d_ff=512 vocab=49155, MoE 40e top-8.
 40 experts pad to 48 on the 16-wide model axis (3/rank).  LSH-MoE applies."""
 from repro.configs.base import (ATTN, MOE, LSHConfig, ModelConfig, MoEConfig)

@@ -39,7 +39,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import default_backend
 from repro.kernels import ref
 from repro.kernels.fused_wire import (dequantize_combine_gather_pallas,
                                       dequantize_residual_apply_pallas,
@@ -367,16 +366,16 @@ def resolve_backend(name: str | None = AUTO, *,
     if name == AUTO:
         name = os.environ.get(ENV_VAR, AUTO) or AUTO
     if name == AUTO:
-        name = PALLAS_TPU if default_backend() == "tpu" else REFERENCE
+        name = PALLAS_TPU if jax.default_backend() == "tpu" else REFERENCE
     if name not in _REGISTRY:
         raise ValueError(f"unknown kernel backend {name!r}; "
                          f"available: {sorted(_REGISTRY)}")
-    if name == PALLAS_TPU and default_backend() != "tpu":
+    if name == PALLAS_TPU and jax.default_backend() != "tpu":
         if off_tpu_fallback is not None:
             return resolve_backend(off_tpu_fallback)
         raise ValueError(
             "kernel backend 'pallas_tpu' requires a TPU (platform is "
-            f"{default_backend()!r}); use 'pallas_interpret' to run "
+            f"{jax.default_backend()!r}); use 'pallas_interpret' to run "
             "the kernel logic off-TPU")
     return name
 

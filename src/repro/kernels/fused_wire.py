@@ -29,9 +29,9 @@ accumulation order, same po2 scale arithmetic — so fused and composed
 paths agree bit-for-bit on every backend, values and (through the
 composite VJPs in comm/wire.py) gradients.
 
-Grids match the unfused kernels: scatter-quantize (E, F/tile_t) with a
-[C, H] f32 VMEM scratch accumulator; dequant-gather (F/tile_t, E);
-dequant-residual (G, C/tile_t).
+Grids match the unfused kernels: scatter-quantize (E, C/tile_c, F/tile_t)
+with a [tile_c, H] f32 VMEM scratch accumulator; dequant-gather
+(F/tile_t, E, C/tile_c); dequant-residual (G, C/tile_t).
 """
 from __future__ import annotations
 
@@ -42,31 +42,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.scatter_gather import sel_mask
+from repro.kernels.scatter_gather import capacity_tile, dot_tn, sel_mask
 from repro.kernels.wire_quant import _encode, po2_scale, qmax, quant_dtype
 
 
 # ------------------------------------------- scatter + quantize (fused) --
+#
+# Scales ride these kernels as [E, C, 1] / [G, S, 1] columns, as in
+# kernels/wire_quant.py, and the capacity axis is tiled like the unfused
+# routing kernels (``capacity_tile``): each row's absmax spans H only, so
+# a [tile_c, H] tile quantizes on its own.
 
 def _scatter_quant_kernel(ids_ref, pos_ref, src_ref, q_ref, scale_ref,
-                          acc_ref, *, capacity, fmt, qmax_val, n_t):
+                          acc_ref, *, tile_c, fmt, qmax_val, n_t):
     e = pl.program_id(0)
-    t = pl.program_id(1)
+    c = pl.program_id(1)
+    t = pl.program_id(2)
 
     @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    sel = sel_mask(ids_ref[0], pos_ref[0], e, capacity, transpose=False)
+    sel = sel_mask(ids_ref[...], pos_ref[...], e, c * tile_c, tile_c)
     src = src_ref[...].astype(jnp.float32)                 # [tile_t, H]
     acc_ref[...] += jnp.dot(sel, src, preferred_element_type=jnp.float32)
 
     @pl.when(t == n_t - 1)
     def _finish():
-        buf = acc_ref[...]                                 # [C, H] f32, VMEM
-        absmax = jnp.max(jnp.abs(buf), axis=-1)            # [C]
-        scale = po2_scale(absmax, qmax_val)
-        q_ref[0] = _encode(buf / scale[:, None], fmt)
+        buf = acc_ref[...]                                 # [tile_c, H] f32
+        absmax = jnp.max(jnp.abs(buf), axis=-1, keepdims=True)
+        scale = po2_scale(absmax, qmax_val)                # [tile_c, 1]
+        q_ref[0] = _encode(buf / scale, fmt)
         scale_ref[0] = scale
 
 
@@ -76,7 +82,7 @@ def dispatch_scatter_quantize_pallas(expert_ids: jax.Array, pos: jax.Array,
                                      src: jax.Array, *, num_experts: int,
                                      capacity: int, fmt: str,
                                      tile_t: int = 128,
-                                     interpret: bool = True):
+                                     interpret: bool):
     """expert_ids/pos: [F] int32; src: [F, H].  Returns
     (q [E, C, H] int8|fp8, scales [E, C] f32) — bit-identical to
     ``wire_quantize(dispatch_scatter(...))`` with the f32 buffer kept in a
@@ -93,45 +99,48 @@ def dispatch_scatter_quantize_pallas(expert_ids: jax.Array, pos: jax.Array,
         src = jnp.pad(src, ((0, pad_f), (0, 0)))
     Fp = F + pad_f
     n_t = Fp // tile_t
-    return pl.pallas_call(
-        functools.partial(_scatter_quant_kernel, capacity=capacity,
+    tile_c = capacity_tile(capacity, H)
+    q, scales = pl.pallas_call(
+        functools.partial(_scatter_quant_kernel, tile_c=tile_c,
                           fmt=fmt, qmax_val=qmax(fmt), n_t=n_t),
-        grid=(num_experts, n_t),
+        grid=(num_experts, capacity // tile_c, n_t),
         in_specs=[
-            pl.BlockSpec((1, tile_t), lambda e, t: (0, t)),
-            pl.BlockSpec((1, tile_t), lambda e, t: (0, t)),
-            pl.BlockSpec((tile_t, H), lambda e, t: (t, 0)),
+            pl.BlockSpec((1, tile_t), lambda e, c, t: (0, t)),
+            pl.BlockSpec((1, tile_t), lambda e, c, t: (0, t)),
+            pl.BlockSpec((tile_t, H), lambda e, c, t: (t, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, capacity, H), lambda e, t: (e, 0, 0)),
-            pl.BlockSpec((1, capacity), lambda e, t: (e, 0)),
+            pl.BlockSpec((1, tile_c, H), lambda e, c, t: (e, c, 0)),
+            pl.BlockSpec((1, tile_c, 1), lambda e, c, t: (e, c, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((num_experts, capacity, H), dt),
-            jax.ShapeDtypeStruct((num_experts, capacity), jnp.float32),
+            jax.ShapeDtypeStruct((num_experts, capacity, 1), jnp.float32),
         ),
-        scratch_shapes=[pltpu.VMEM((capacity, H), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tile_c, H), jnp.float32)],
         interpret=interpret,
     )(ids, p, src)
+    return q, scales[..., 0]
 
 
 # ------------------------------------------- dequantize + gather (fused) --
 
 def _dequant_gather_kernel(ids_ref, pos_ref, w_ref, q_ref, scale_ref,
-                           out_ref, *, capacity):
+                           out_ref, *, tile_c):
     e = pl.program_id(1)
+    c = pl.program_id(2)
 
-    @pl.when(e == 0)
+    @pl.when((e == 0) & (c == 0))
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    sel = sel_mask(ids_ref[0], pos_ref[0], e, capacity, transpose=True)
-    w = w_ref[0].astype(jnp.float32)                       # [tile_t]
-    # dequantize the [C, H] expert block in VREGs — the f32 buffer the
-    # unfused path would have written to HBM never leaves the registers
-    buf = q_ref[0].astype(jnp.float32) * scale_ref[0][:, None]
-    out_ref[...] += w[:, None] * jnp.dot(
-        sel, buf, preferred_element_type=jnp.float32)
+    sel = sel_mask(ids_ref[...], pos_ref[...], e, c * tile_c, tile_c)
+    w = w_ref[...].astype(jnp.float32)                     # [1, tile_t]
+    # dequantize the [tile_c, H] expert block in VREGs — the f32 buffer
+    # the unfused path would have written to HBM never leaves the
+    # registers
+    buf = q_ref[0].astype(jnp.float32) * scale_ref[0]
+    out_ref[...] += dot_tn(sel * w, buf)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_t", "interpret"))
@@ -139,7 +148,7 @@ def dequantize_combine_gather_pallas(expert_ids: jax.Array, pos: jax.Array,
                                      q: jax.Array, scales: jax.Array,
                                      weights: jax.Array, *,
                                      tile_t: int = 128,
-                                     interpret: bool = True) -> jax.Array:
+                                     interpret: bool) -> jax.Array:
     """expert_ids/pos: [F] int32; q: [E, C, H] int8|fp8; scales: [E, C];
     weights: [F].  Returns [F, H] f32 = weights[f] * (q * scale)[id_f,
     pos_f] — bit-identical to ``combine_gather(ids, pos,
@@ -156,20 +165,21 @@ def dequantize_combine_gather_pallas(expert_ids: jax.Array, pos: jax.Array,
         p = jnp.pad(p, ((0, 0), (0, pad_f)))
         w = jnp.pad(w, ((0, 0), (0, pad_f)))
     Fp = F + pad_f
+    tile_c = capacity_tile(C, H)
     out = pl.pallas_call(
-        functools.partial(_dequant_gather_kernel, capacity=C),
-        grid=(Fp // tile_t, E),
+        functools.partial(_dequant_gather_kernel, tile_c=tile_c),
+        grid=(Fp // tile_t, E, C // tile_c),
         in_specs=[
-            pl.BlockSpec((1, tile_t), lambda t, e: (0, t)),
-            pl.BlockSpec((1, tile_t), lambda t, e: (0, t)),
-            pl.BlockSpec((1, tile_t), lambda t, e: (0, t)),
-            pl.BlockSpec((1, C, H), lambda t, e: (e, 0, 0)),
-            pl.BlockSpec((1, C), lambda t, e: (e, 0)),
+            pl.BlockSpec((1, tile_t), lambda t, e, c: (0, t)),
+            pl.BlockSpec((1, tile_t), lambda t, e, c: (0, t)),
+            pl.BlockSpec((1, tile_t), lambda t, e, c: (0, t)),
+            pl.BlockSpec((1, tile_c, H), lambda t, e, c: (e, c, 0)),
+            pl.BlockSpec((1, tile_c, 1), lambda t, e, c: (e, c, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_t, H), lambda t, e: (t, 0)),
+        out_specs=pl.BlockSpec((tile_t, H), lambda t, e, c: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, H), jnp.float32),
         interpret=interpret,
-    )(ids, p, w, q, scales)
+    )(ids, p, w, q, scales.reshape(E, C, 1))
     return out[:F]
 
 
@@ -177,27 +187,25 @@ def dequantize_combine_gather_pallas(expert_ids: jax.Array, pos: jax.Array,
 
 def _dq_resid_kernel(slots_ref, q_ref, scale_ref, resid_ref, out_ref, *,
                      num_slots):
-    slots = slots_ref[0]                                   # [tile_t]
-    dq = q_ref[0].astype(jnp.float32) * scale_ref[0][:, None]  # [S, H]
+    slots = slots_ref[0]                                   # [1, tile_t]
+    dq = q_ref[0].astype(jnp.float32) * scale_ref[0]       # [S, H]
     resid = resid_ref[0].astype(jnp.float32)               # [tile_t, H]
     onehot = (jax.lax.broadcasted_iota(jnp.int32,
-                                       (slots.shape[0], num_slots), 1)
-              == slots[:, None]).astype(jnp.float32)
-    gathered = jnp.dot(onehot, dq, preferred_element_type=jnp.float32)
-    out_ref[0] = gathered + resid
+                                       (num_slots, slots.shape[1]), 0)
+              == slots).astype(jnp.float32)                # [S, tile_t]
+    out_ref[0] = dot_tn(onehot, dq) + resid
 
 
 def _dq_resid_base_kernel(slots_ref, q_ref, scale_ref, base_ref, resid_ref,
                           out_ref, *, num_slots):
     slots = slots_ref[0]
-    dq = q_ref[0].astype(jnp.float32) * scale_ref[0][:, None]
+    dq = q_ref[0].astype(jnp.float32) * scale_ref[0]
     delta = dq - base_ref[0].astype(jnp.float32)           # [S, H]
     resid = resid_ref[0].astype(jnp.float32)
     onehot = (jax.lax.broadcasted_iota(jnp.int32,
-                                       (slots.shape[0], num_slots), 1)
-              == slots[:, None]).astype(jnp.float32)
-    gathered = jnp.dot(onehot, delta, preferred_element_type=jnp.float32)
-    out_ref[0] = gathered + resid
+                                       (num_slots, slots.shape[1]), 0)
+              == slots).astype(jnp.float32)
+    out_ref[0] = dot_tn(onehot, delta) + resid
 
 
 @functools.partial(jax.jit, static_argnames=("tile_t", "interpret"))
@@ -205,7 +213,7 @@ def dequantize_residual_apply_pallas(slots: jax.Array, q: jax.Array,
                                      scales: jax.Array, residual: jax.Array,
                                      base: jax.Array = None, *,
                                      tile_t: int = 128,
-                                     interpret: bool = True) -> jax.Array:
+                                     interpret: bool) -> jax.Array:
     """slots: [G, C] int32; q: [G, S, H] int8|fp8; scales: [G, S];
     residual: [G, C, H]; base: optional [G, S, H].  Returns [G, C, H] f32
     = ((q * scale) - base)[g, slots] + residual — bit-identical to
@@ -219,11 +227,11 @@ def dequantize_residual_apply_pallas(slots: jax.Array, q: jax.Array,
         slots = jnp.pad(slots, ((0, 0), (0, pad_c)), constant_values=-1)
     Cp = C + pad_c
     in_specs = [
-        pl.BlockSpec((1, tile_t), lambda g, t: (g, t)),
+        pl.BlockSpec((1, 1, tile_t), lambda g, t: (g, 0, t)),
         pl.BlockSpec((1, S, H), lambda g, t: (g, 0, 0)),
-        pl.BlockSpec((1, S), lambda g, t: (g, 0)),
+        pl.BlockSpec((1, S, 1), lambda g, t: (g, 0, 0)),
     ]
-    operands = [slots, q, scales]
+    operands = [slots.reshape(G, 1, Cp), q, scales.reshape(G, S, 1)]
     if base is not None:
         in_specs.append(pl.BlockSpec((1, S, H), lambda g, t: (g, 0, 0)))
         operands.append(base)

@@ -16,22 +16,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.scatter_gather import dot_tn
+
 
 def _kernel(slots_ref, eout_ref, resid_ref, out_ref, *, num_slots):
-    slots = slots_ref[0]                          # [tile_t]
+    slots = slots_ref[0]                          # [1, tile_t]
     eout = eout_ref[0].astype(jnp.float32)        # [S, H]
     resid = resid_ref[0].astype(jnp.float32)      # [tile_t, H]
     onehot = (jax.lax.broadcasted_iota(jnp.int32,
-                                       (slots.shape[0], num_slots), 1)
-              == slots[:, None]).astype(jnp.float32)
-    gathered = jnp.dot(onehot, eout, preferred_element_type=jnp.float32)
+                                       (num_slots, slots.shape[1]), 0)
+              == slots).astype(jnp.float32)       # [S, tile_t]
+    gathered = dot_tn(onehot, eout)
     out_ref[0] = (gathered + resid).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_t", "interpret"))
 def residual_apply_pallas(slots: jax.Array, expert_out: jax.Array,
                           residual: jax.Array, *, tile_t: int = 128,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool) -> jax.Array:
     """slots: [G, C] int32; expert_out: [G, S, H]; residual: [G, C, H].
     Returns [G, C, H] = expert_out[g, slots] + residual (f32)."""
     G, C, H = residual.shape
@@ -45,12 +47,14 @@ def residual_apply_pallas(slots: jax.Array, expert_out: jax.Array,
         functools.partial(_kernel, num_slots=S),
         grid=(G, Cp // tile_t),
         in_specs=[
-            pl.BlockSpec((1, tile_t), lambda g, t: (g, t)),
+            # ids ride as [G, 1, C] so the block's last two dims are whole
+            # or (8, 128)-aligned, as Mosaic requires
+            pl.BlockSpec((1, 1, tile_t), lambda g, t: (g, 0, t)),
             pl.BlockSpec((1, S, H), lambda g, t: (g, 0, 0)),
             pl.BlockSpec((1, tile_t, H), lambda g, t: (g, t, 0)),
         ],
         out_specs=pl.BlockSpec((1, tile_t, H), lambda g, t: (g, t, 0)),
         out_shape=jax.ShapeDtypeStruct((G, Cp, H), jnp.float32),
         interpret=interpret,
-    )(slots, expert_out, residual)
+    )(slots.reshape(G, 1, Cp), expert_out, residual)
     return out[:, :C]

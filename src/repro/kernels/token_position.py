@@ -49,7 +49,7 @@ def _kernel(ids_ref, pos_ref, counts_ref, *, num_experts, tile_t):
 @functools.partial(jax.jit,
                    static_argnames=("num_experts", "tile_t", "interpret"))
 def positions_in_expert_pallas(expert_ids: jax.Array, *, num_experts: int,
-                               tile_t: int = 128, interpret: bool = True):
+                               tile_t: int = 128, interpret: bool):
     """expert_ids: [F] int32.  Returns (pos [F] int32, counts [E] f32):
     pos[f] = |{g < f : id_g == id_f}| (token-major stability — earlier
     entries win buffer rows), counts[e] = uncapped total routed to e.

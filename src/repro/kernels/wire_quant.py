@@ -105,23 +105,27 @@ def _quant_kernel(x_ref, q_ref, scale_ref, *, fmt, qmax_val, num_rows,
     # payload, scale 1, whatever the pad values were) instead of having
     # scales computed for them.
     s = pl.program_id(1)
-    row = s * tile_s + jax.lax.broadcasted_iota(jnp.int32, (tile_s,), 0)
-    valid = (row < num_rows).astype(jnp.float32)       # [tile_s]
-    x = x_ref[0].astype(jnp.float32) * valid[:, None]  # [tile_s, H]
-    absmax = jnp.max(jnp.abs(x), axis=-1)              # [tile_s]
+    x = x_ref[0].astype(jnp.float32)                   # [tile_s, H]
+    row = s * tile_s + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    x = jnp.where(row < num_rows, x, 0.0)
+    absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)   # [tile_s, 1]
     scale = po2_scale(absmax, qmax_val)
-    q_ref[0] = _encode(x / scale[:, None], fmt)
+    q_ref[0] = _encode(x / scale, fmt)
     scale_ref[0] = scale
 
 
 def _dequant_kernel(q_ref, scale_ref, out_ref):
     q = q_ref[0].astype(jnp.float32)                   # [tile_s, H]
-    out_ref[0] = q * scale_ref[0][:, None]
+    out_ref[0] = q * scale_ref[0]                      # scale: [tile_s, 1]
 
+
+# Scales ride the kernels as [G, S, 1] columns: the per-row reduction
+# lands there naturally, and the block's last two dims stay whole or
+# (8, 128)-aligned, as Mosaic requires.
 
 @functools.partial(jax.jit, static_argnames=("fmt", "tile_s", "interpret"))
 def wire_quantize_pallas(x: jax.Array, *, fmt: str, tile_s: int = 8,
-                         interpret: bool = True):
+                         interpret: bool):
     """x: [G, S, H] -> (q [G, S, H] int8|fp8, scales [G, S] f32).
 
     One power-of-two absmax scale per (group, slot) row; all-zero rows get
@@ -139,20 +143,20 @@ def wire_quantize_pallas(x: jax.Array, *, fmt: str, tile_s: int = 8,
         in_specs=[pl.BlockSpec((1, tile_s, H), lambda g, s: (g, s, 0))],
         out_specs=(
             pl.BlockSpec((1, tile_s, H), lambda g, s: (g, s, 0)),
-            pl.BlockSpec((1, tile_s), lambda g, s: (g, s)),
+            pl.BlockSpec((1, tile_s, 1), lambda g, s: (g, s, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((G, Sp, H), dt),
-            jax.ShapeDtypeStruct((G, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((G, Sp, 1), jnp.float32),
         ),
         interpret=interpret,
     )(x)
-    return q[:, :S], scales[:, :S]
+    return q[:, :S], scales[:, :S, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_s", "interpret"))
 def wire_dequantize_pallas(q: jax.Array, scales: jax.Array, *,
-                           tile_s: int = 8, interpret: bool = True):
+                           tile_s: int = 8, interpret: bool):
     """(q [G, S, H], scales [G, S]) -> [G, S, H] f32 = q * scale."""
     G, S, H = q.shape
     pad_s = (-S) % tile_s
@@ -165,10 +169,10 @@ def wire_dequantize_pallas(q: jax.Array, scales: jax.Array, *,
         grid=(G, Sp // tile_s),
         in_specs=[
             pl.BlockSpec((1, tile_s, H), lambda g, s: (g, s, 0)),
-            pl.BlockSpec((1, tile_s), lambda g, s: (g, s)),
+            pl.BlockSpec((1, tile_s, 1), lambda g, s: (g, s, 0)),
         ],
         out_specs=pl.BlockSpec((1, tile_s, H), lambda g, s: (g, s, 0)),
         out_shape=jax.ShapeDtypeStruct((G, Sp, H), jnp.float32),
         interpret=interpret,
-    )(q, scales)
+    )(q, scales.reshape(G, Sp, 1))
     return out[:, :S]

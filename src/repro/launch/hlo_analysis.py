@@ -15,11 +15,14 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-# TPU v5e-class hardware constants — the shared datasheet (repro.hw),
-# aliased to the names this module has always exported.
-from repro.hw import DEVICE_FLOPS as PEAK_FLOPS
-from repro.hw import HBM_BYTES_PER_S as HBM_BW
-from repro.hw import ICI_BYTES_PER_S as ICI_BW
+from repro import hw
+
+# The dry run prices the production mesh's chip, a TPU v5e, whatever host
+# compiles it.
+_CHIP = hw.peaks(hw.V5E)
+PEAK_FLOPS = _CHIP.flops
+HBM_BW = _CHIP.hbm_bytes_per_s
+ICI_BW = _CHIP.ici_bytes_per_s
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -174,8 +177,6 @@ def analyze(compiled) -> Roofline:
     """
     from repro.launch import hlo_structural
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # JAX 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     st = hlo_structural.analyze_text(compiled.as_text())
     r = Roofline(

@@ -51,13 +51,13 @@ def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1,
     if multi_pod:
         shape, axes = (2,) + shape, ("pod",) + axes
     n = int(np.prod(shape))
-    if len(jax.devices()) == n and hasattr(jax.sharding, "AxisType"):
-        # newer JAX: let make_mesh pick the device order for the topology
+    if len(jax.devices()) == n:
+        # let make_mesh pick the device order for the topology
         mesh = jax.make_mesh(shape, axes,
                              axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     else:
-        # JAX 0.4.x (no AxisType), or fewer/more devices than the full
-        # mesh: a row-major prefix (the dry-run path)
+        # fewer/more devices than the full mesh: a row-major prefix (the
+        # dry-run path)
         devs = np.array(jax.devices()[:n]).reshape(shape)
         mesh = Mesh(devs, axes)
     register_node_size(mesh, node_size)

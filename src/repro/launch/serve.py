@@ -49,11 +49,13 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
     from repro.compat import set_mesh
     from repro.configs.registry import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import model as model_lib
     from repro.obs import events as obs_events
     from repro.obs import export as obs_export
 
+    enable_compile_cache()
     log = obs_events.global_log()
     log.add_sink(obs_events.ConsoleSink())
     jsonl = None
@@ -71,7 +73,11 @@ def main(argv=None) -> int:
 
     try:
         with set_mesh(mesh):
-            params = model_lib.init_params(jax.random.PRNGKey(0), cfg, mesh)
+            # one program, so each weight is drawn straight into its dtype:
+            # eagerly, granite's 32-layer expert stacks would each pass
+            # through two 3.75 GiB f32 temporaries
+            params = jax.jit(lambda k: model_lib.init_params(k, cfg, mesh))(
+                jax.random.PRNGKey(0))
             decode = jax.jit(
                 lambda p, s, t: model_lib.decode_step(p, cfg, mesh, s, t))
             key = jax.random.PRNGKey(1)

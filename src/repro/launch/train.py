@@ -175,6 +175,7 @@ def main() -> int:
     from repro.compat import set_mesh
     from repro.configs.base import OptimizerConfig
     from repro.configs.registry import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.obs import events as obs_events
     from repro.obs import export as obs_export
@@ -186,6 +187,7 @@ def main() -> int:
     from repro.runtime.step import (TrainState, init_train_state,
                                     make_train_step)
 
+    enable_compile_cache()
     log = obs_events.global_log()
     log.add_sink(obs_events.ConsoleSink())
     mem = obs_events.MemorySink()
@@ -297,14 +299,9 @@ def main() -> int:
         profile_analyzed = True
         from repro.obs import profile as obs_profile
         from repro.obs import reconcile as obs_reconcile
-        try:
-            measured = obs_profile.parse_jax_trace(
-                os.path.join(args.metrics_dir, "jax_trace"),
-                hlo_text=step_hlo_text, steps=steps_profiled,
-                n_devices=n_mesh)
-        except Exception as exc:
-            obs_events.emit("error", where="profile", message=str(exc))
-            return
+        measured = obs_profile.parse_jax_trace(
+            os.path.join(args.metrics_dir, "jax_trace"),
+            hlo_text=step_hlo_text, steps=steps_profiled, n_devices=n_mesh)
         profile_extra.update(measured.summary())
         if not modeled_phase_s:
             return
@@ -314,16 +311,12 @@ def main() -> int:
         profile_extra.update(report.to_metrics())
         if cfg.has_moe() \
                 and tune_runtime.tuning_mode(comm_cfg) != "off":
-            try:
-                entry = obs_reconcile.record_stale_calibration(
-                    mesh, comm_cfg, report)
-                if entry is not None and report.stale:
-                    obs_events.emit("tune_stale", path=entry,
-                                    comm_drift=report.comm_drift,
-                                    drift_score=report.drift_score)
-            except Exception as exc:
-                obs_events.emit("error", where="reconcile",
-                                message=str(exc))
+            entry = obs_reconcile.record_stale_calibration(
+                mesh, comm_cfg, report)
+            if entry is not None and report.stale:
+                obs_events.emit("tune_stale", path=entry,
+                                comm_drift=report.comm_drift,
+                                drift_score=report.drift_score)
 
     def export_artifacts(final_metrics=None):
         if not args.metrics_dir:
@@ -359,21 +352,14 @@ def main() -> int:
 
     def start_profile():
         nonlocal profiling
-        try:
-            jax.profiler.start_trace(
-                os.path.join(args.metrics_dir, "jax_trace"))
-            profiling = True
-        except Exception as exc:         # profiler backend unavailable
-            obs_events.emit("error", where="profiler", message=str(exc))
+        jax.profiler.start_trace(os.path.join(args.metrics_dir, "jax_trace"))
+        profiling = True
 
     def stop_profile():
         nonlocal profiling, profile_done
         if profiling:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as exc:
-                obs_events.emit("error", where="profiler", message=str(exc))
             profiling = False
+            jax.profiler.stop_trace()
         profile_done = True
 
     metrics = {}
@@ -393,12 +379,8 @@ def main() -> int:
                 # The compiled text's op_name metadata is what lets the
                 # trace parser resolve CPU/GPU fusion names back to the
                 # obs/ phase scopes (obs/profile.hlo_phase_map).
-                try:
-                    step_hlo_text = step_fn.lower(
-                        state, ds.batch_at(start)).compile().as_text()
-                except Exception as exc:
-                    obs_events.emit("error", where="profiler",
-                                    message=f"step HLO capture: {exc}")
+                step_hlo_text = step_fn.lower(
+                    state, ds.batch_at(start)).compile().as_text()
             for s in range(start, args.steps):
                 if profile_requested and not profiling and not profile_done \
                         and (s == start + 1
@@ -420,14 +402,10 @@ def main() -> int:
                     # The first step traced the real comm plan — derive
                     # the phase attribution weights from it (calibrated
                     # topology costs + analytic FLOPs).
-                    try:
-                        modeled_phase_s = timeline_lib.model_phase_seconds(
-                            cfg, mesh, batch=args.batch, seq=args.seq,
-                            stage_msg_bytes=stage_msg_bytes)
-                        timeline.set_phase_seconds(modeled_phase_s)
-                    except Exception as exc:
-                        obs_events.emit("error", where="timeline",
-                                        message=str(exc))
+                    modeled_phase_s = timeline_lib.model_phase_seconds(
+                        cfg, mesh, batch=args.batch, seq=args.seq,
+                        stage_msg_bytes=stage_msg_bytes)
+                    timeline.set_phase_seconds(modeled_phase_s)
                 if profiling:
                     steps_profiled += 1
                     if steps_profiled >= args.profile:

@@ -39,9 +39,11 @@ PHASE_ORDER = ("gate", "hash_compress", "dispatch_a2a", "expert_mlp",
                "combine_a2a", "decompress", "stage_transfer", "other")
 COMM_PHASES = ("dispatch_a2a", "combine_a2a", "stage_transfer")
 
-# Default device throughput for the analytic compute model — the shared
-# v5e datasheet constant (repro.hw), re-exported for existing callers.
-from repro.hw import DEVICE_FLOPS
+# Default device throughput for the analytic compute model: the TPU v5e's
+# published bf16 peak (repro.hw), whatever host runs the model.
+from repro import hw
+
+DEVICE_FLOPS = hw.peaks(hw.V5E).flops
 
 
 @dataclass(frozen=True)

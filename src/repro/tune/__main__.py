@@ -58,11 +58,13 @@ def main(argv=None) -> int:
 
     import jax                            # first jax touch — after XLA_FLAGS
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.obs import events as obs_events
     from repro.obs import export as obs_export
     from repro.tune.autotune import DEFAULT_LADDER, autotune
 
+    enable_compile_cache()
     log = obs_events.global_log()
     log.add_sink(obs_events.ConsoleSink())
     jsonl = None

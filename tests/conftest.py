@@ -4,12 +4,6 @@ import pytest
 
 import jax
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:                   # gated dep: container may not ship it
-    from _hypothesis_stub import install
-    install()
-
 
 @pytest.fixture(scope="session")
 def mesh():

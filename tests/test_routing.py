@@ -1,7 +1,6 @@
 """DispatchPlan / positions_in_expert properties: stability, capacity
 overflow, degenerate routings, and plan-level invariants shared by both
-MoE paths.  Property tests run under hypothesis (or the deterministic
-stub in tests/_hypothesis_stub.py when it is not installed)."""
+MoE paths.  Property tests run under hypothesis."""
 import jax
 import jax.numpy as jnp
 import numpy as np
