@@ -34,6 +34,8 @@ from functools import partial
 import jax
 
 from repro.comm.collectives import _raw_a2a
+from repro.obs import tracing as obs_tracing
+from repro.obs.tracing import phase_scope
 
 
 def intra_groups(r: int, intra: int):
@@ -86,5 +88,8 @@ hierarchical_all_to_all_bf16.defvjp(_hier_fwd, _hier_bwd)
 def hierarchical_moe_exchange(send, compute_fn, axis_name: str, intra: int):
     """dispatch a2a -> compute -> combine a2a, both hops hierarchical.
     send: [R, e_local, c, H]; compute_fn keeps that shape."""
-    recv = hierarchical_all_to_all_bf16(send, axis_name, intra)
-    return hierarchical_all_to_all_bf16(compute_fn(recv), axis_name, intra)
+    with phase_scope(obs_tracing.PH_DISPATCH):
+        recv = hierarchical_all_to_all_bf16(send, axis_name, intra)
+    out = compute_fn(recv)
+    with phase_scope(obs_tracing.PH_COMBINE):
+        return hierarchical_all_to_all_bf16(out, axis_name, intra)

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.comm.collectives import all_to_all_bf16
+from repro.obs import tracing as obs_tracing
+from repro.obs.tracing import phase_scope
 
 
 def _slice(x, i, size, axis):
@@ -108,20 +110,27 @@ def pipelined_moe_exchange(send, compute_fn, axis_name: str, chunks: int,
     if transfer is None:
         def transfer(v):
             return all_to_all_bf16(v, axis_name, 0, 0)
+
+    def dispatch(v):
+        with phase_scope(obs_tracing.PH_DISPATCH):
+            return transfer(v)
+
+    def finish(chunk):
+        out = compute_fn(chunk)
+        with phase_scope(obs_tracing.PH_COMBINE):
+            return transfer(out)
+
     extent = send.shape[chunk_axis]
     _check_divides(chunks, extent)
     if chunks <= 1:
-        return transfer(compute_fn(transfer(send)))
+        return finish(dispatch(send))
     size = extent // chunks
 
-    def finish(chunk):
-        return transfer(compute_fn(chunk))
-
-    recv0 = transfer(_slice(send, 0, size, chunk_axis))
+    recv0 = dispatch(_slice(send, 0, size, chunk_axis))
 
     def body(i, carry):
         out, prev = carry
-        nxt = transfer(_slice(send, i, size, chunk_axis))  # transfer chunk i
+        nxt = dispatch(_slice(send, i, size, chunk_axis))  # transfer chunk i
         done = finish(prev)                                # compute chunk i-1
         return _update(out, done, i - 1, size, chunk_axis), nxt
 

@@ -58,6 +58,8 @@ from repro.kernels import dispatch
 from repro.kernels.dispatch import _float0_like
 from repro.kernels.wire_quant import (BF16_FORMAT, QUANT_FORMATS,
                                       WIRE_FORMATS, validate_wire_format)
+from repro.obs import tracing as obs_tracing
+from repro.obs.tracing import phase_scope
 
 FUSED_ENV = "REPRO_FUSED_WIRE"
 
@@ -196,8 +198,11 @@ def coded_moe_exchange(send, compute_fn, codec: WireCodec, fwd_leaf,
     """dispatch a2a -> compute_fn -> combine a2a, both legs coded.
     ``send``: float [R, e_local, c, H]; ``compute_fn`` maps the decoded
     (``compute_dtype``) tensor to the same shape."""
-    recv = coded_transfer(send, codec, fwd_leaf, bwd_leaf)
-    return coded_transfer(compute_fn(recv), codec, fwd_leaf, bwd_leaf)
+    with phase_scope(obs_tracing.PH_DISPATCH):
+        recv = coded_transfer(send, codec, fwd_leaf, bwd_leaf)
+    out = compute_fn(recv)
+    with phase_scope(obs_tracing.PH_COMBINE):
+        return coded_transfer(out, codec, fwd_leaf, bwd_leaf)
 
 
 # ------------------------------------------------------ fused transfers --

@@ -141,12 +141,6 @@ def _local_moe(x, router_w, w_gate, w_up, w_down, rot, placement, *,
     T = B_loc * S_loc
     xf = x.reshape(T, H)
 
-    with phase_scope(obs_tracing.PH_GATE):
-        gate = top_k_gating(xf, router_w, cfg.top_k, placement)
-        plan = routing.build_dispatch_plan(gate.expert_ids, gate.weights,
-                                           e_pad, capacity,
-                                           backend=kernel_backend)
-
     # Fused codec path (comm/wire.py, kernels/fused_wire.py): quantized
     # wire + a transport whose leaves move whole — the codec runs INSIDE
     # the scatter/gather kernels and the f32 wire tensor never reaches
@@ -158,10 +152,25 @@ def _local_moe(x, router_w, w_gate, w_up, w_down, rot, placement, *,
              and cplan.transport != comm_planner.PIPELINED
              and wire_lib.fused_wire_enabled())
 
+    with phase_scope(obs_tracing.PH_GATE):
+        gate = top_k_gating(xf, router_w, cfg.top_k, placement)
+        plan = routing.build_dispatch_plan(gate.expert_ids, gate.weights,
+                                           e_pad, capacity,
+                                           backend=kernel_backend)
+        # The dispatch scatter executes the plan, with LSH on or off.  The
+        # quantized non-LSH baseline (wire_format int8/fp8, LSH off) keeps
+        # the f32 buffer — the unfused leg encodes the same buffer the
+        # fused kernel quantizes, keeping the two paths bit-identical —
+        # and the fused one skips it (the scatter runs inside the
+        # transfer).
+        disp = None
+        if use_lsh or not fused:
+            disp = routing.dispatch_tokens(plan, xf, backend=kernel_backend)
+            if use_lsh or codec is None:
+                disp = disp.astype(xf.dtype)
+
     if use_lsh:
         with phase_scope(obs_tracing.PH_COMPRESS):
-            disp = routing.dispatch_tokens(
-                plan, xf, backend=kernel_backend).astype(xf.dtype)
             # Residuals are computed against the DEQUANTIZED wire
             # centroids, so the codec's in-transit encode (comm/wire.py)
             # is exactly loss-transparent at the combine step.
@@ -172,18 +181,9 @@ def _local_moe(x, router_w, w_gate, w_up, w_down, rot, placement, *,
                                        wire_format=cfg.lsh.wire_format,
                                        wire_dtype=wire_dtype)
         wire, c_wire = comp.centroids, lsh_slots
-    elif codec is not None:
-        # Quantized non-LSH baseline (wire_format int8/fp8 with LSH off):
-        # the raw dispatch buffer crosses the wire coded.  It stays f32 —
-        # the unfused leg encodes the same buffer the fused kernel
-        # quantizes, keeping the two paths bit-identical; fused skips
-        # building it entirely (the scatter happens inside the transfer).
-        comp, c_wire = None, capacity
-        wire = None if fused else routing.dispatch_tokens(
-            plan, xf, backend=kernel_backend)
     else:
-        disp = routing.dispatch_tokens(plan, xf,
-                                       backend=kernel_backend).astype(xf.dtype)
+        # Without LSH the raw dispatch buffer crosses the wire (coded in
+        # transit where a codec is set).
         comp, wire, c_wire = None, disp, capacity
 
     # ---- wire exchange: dispatch a2a -> expert MLP -> combine a2a, with
@@ -352,6 +352,8 @@ def moe_expert_parallel(x: jax.Array, params: Dict, cfg: MoEConfig,
         out_specs=(tok_spec, P(), P(), P(), P(), P()) if obs_on
         else (tok_spec, P(), P(), P()),
     )
+    # The train step activates the scopes already; this covers the layer
+    # traced outside a step (the model's loss alone, prefill).
     with obs_tracing.activate(cfg.obs.phase_tracing):
         out = mapped(x, params["router_w"], params.get("w_gate"),
                      params["w_up"], params["w_down"], params["lsh_rot"],

@@ -118,6 +118,7 @@ def dispatch_scatter_quantize_pallas(expert_ids: jax.Array, pos: jax.Array,
             jax.ShapeDtypeStruct((num_experts, capacity, 1), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((tile_c, H), jnp.float32)],
+        name="dispatch_scatter_quantize_pallas",
         interpret=interpret,
     )(ids, p, src)
     return q, scales[..., 0]
@@ -178,6 +179,7 @@ def dequantize_combine_gather_pallas(expert_ids: jax.Array, pos: jax.Array,
         ],
         out_specs=pl.BlockSpec((tile_t, H), lambda t, e, c: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, H), jnp.float32),
+        name="dequantize_combine_gather_pallas",
         interpret=interpret,
     )(ids, p, w, q, scales.reshape(E, C, 1))
     return out[:F]
@@ -246,6 +248,7 @@ def dequantize_residual_apply_pallas(slots: jax.Array, q: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tile_t, H), lambda g, t: (g, t, 0)),
         out_shape=jax.ShapeDtypeStruct((G, Cp, H), jnp.float32),
+        name="dequantize_residual_apply_pallas",
         interpret=interpret,
     )(*operands)
     return out[:, :C]

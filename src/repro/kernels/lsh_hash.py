@@ -68,6 +68,7 @@ def lsh_hash_pallas(x: jax.Array, rotations: jax.Array, *, tile_t: int = 128,
         ],
         out_specs=pl.BlockSpec((tile_t, L), lambda t: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, L), jnp.int32),
+        name="lsh_hash_pallas",
         interpret=interpret,
     )(x, rotations)
     return out[:T]

@@ -55,6 +55,7 @@ def residual_apply_pallas(slots: jax.Array, expert_out: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, tile_t, H), lambda g, t: (g, t, 0)),
         out_shape=jax.ShapeDtypeStruct((G, Cp, H), jnp.float32),
+        name="residual_apply_pallas",
         interpret=interpret,
     )(slots.reshape(G, 1, Cp), expert_out, residual)
     return out[:, :C]

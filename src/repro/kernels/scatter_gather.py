@@ -114,6 +114,7 @@ def dispatch_scatter_pallas(expert_ids: jax.Array, pos: jax.Array,
         out_specs=pl.BlockSpec((1, tile_c, H), lambda e, c, t: (e, c, 0)),
         out_shape=jax.ShapeDtypeStruct((num_experts, capacity, H),
                                        jnp.float32),
+        name="dispatch_scatter_pallas",
         interpret=interpret,
     )(ids, p, src)
 
@@ -165,6 +166,7 @@ def combine_gather_pallas(expert_ids: jax.Array, pos: jax.Array,
         ],
         out_specs=pl.BlockSpec((tile_t, H), lambda t, e, c: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, H), jnp.float32),
+        name="combine_gather_pallas",
         interpret=interpret,
     )(ids, p, w, buf)
     return out[:F]

@@ -70,6 +70,7 @@ def segment_centroid_pallas(slots: jax.Array, x: jax.Array, *,
             jax.ShapeDtypeStruct((G, num_slots, H), jnp.float32),
             jax.ShapeDtypeStruct((G, num_slots, 1), jnp.float32),
         ),
+        name="segment_centroid_pallas",
         interpret=interpret,
     )(slots.reshape(G, 1, Cp), x)
     counts = counts[..., 0]

@@ -72,6 +72,7 @@ def positions_in_expert_pallas(expert_ids: jax.Array, *, num_experts: int,
             jax.ShapeDtypeStruct((1, Fp), jnp.int32),
             jax.ShapeDtypeStruct((1, num_experts), jnp.float32),
         ),
+        name="positions_in_expert_pallas",
         interpret=interpret,
     )(ids)
     return pos[0, :F], counts[0]

@@ -149,6 +149,7 @@ def wire_quantize_pallas(x: jax.Array, *, fmt: str, tile_s: int = 8,
             jax.ShapeDtypeStruct((G, Sp, H), dt),
             jax.ShapeDtypeStruct((G, Sp, 1), jnp.float32),
         ),
+        name="wire_quantize_pallas",
         interpret=interpret,
     )(x)
     return q[:, :S], scales[:, :S, 0]
@@ -173,6 +174,7 @@ def wire_dequantize_pallas(q: jax.Array, scales: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, tile_s, H), lambda g, s: (g, s, 0)),
         out_shape=jax.ShapeDtypeStruct((G, Sp, H), jnp.float32),
+        name="wire_dequantize_pallas",
         interpret=interpret,
     )(q, scales.reshape(G, Sp, 1))
     return out[:, :S]

@@ -171,6 +171,7 @@ def main() -> int:
         return supervise(sys.argv[1:])
 
     import jax
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
     from repro.checkpoint.checkpoint import CheckpointManager, load_checkpoint
     from repro.compat import set_mesh
     from repro.configs.base import OptimizerConfig
@@ -386,86 +387,96 @@ def main() -> int:
                         and (s == start + 1
                              or args.steps - start == 1):
                     start_profile()
-                batch = ds.batch_at(s)
-                watchdog.arm()
-                if chaos is not None:
-                    # after arm(): a hang fault must trip the watchdog
-                    chaos.on_step_start(s)
-                    batch = chaos.chaos_batch(batch, s)
-                timeline.start(s)
-                state, metrics = step_fn(state, batch)
-                loss = float(metrics["loss"])  # blocks; completes the step
-                watchdog.disarm()
-                rec = timeline.stop(s)
-                dt = rec.duration
-                if s == start:
-                    # The first step traced the real comm plan — derive
-                    # the phase attribution weights from it (calibrated
-                    # topology costs + analytic FLOPs).
-                    modeled_phase_s = timeline_lib.model_phase_seconds(
-                        cfg, mesh, batch=args.batch, seq=args.seq,
-                        stage_msg_bytes=stage_msg_bytes)
-                    timeline.set_phase_seconds(modeled_phase_s)
+                # Host spans on the profiler's clock: a --profile trace
+                # names each idle gap by them, as chipbench/trace.py does.
+                with StepTraceAnnotation("train", step_num=s):
+                    with TraceAnnotation("batch"):
+                        batch = ds.batch_at(s)
+                    watchdog.arm()
+                    if chaos is not None:
+                        # after arm(): a hang fault must trip the watchdog
+                        chaos.on_step_start(s)
+                        batch = chaos.chaos_batch(batch, s)
+                    timeline.start(s)
+                    with TraceAnnotation("dispatch"):
+                        state, metrics = step_fn(state, batch)
+                    with TraceAnnotation("wait"):
+                        # blocks; completes the step
+                        loss = float(metrics["loss"])
+                    watchdog.disarm()
+                    rec = timeline.stop(s)
+                    dt = rec.duration
+                    if s == start:
+                        # The first step traced the real comm plan — derive
+                        # the phase attribution weights from it (calibrated
+                        # topology costs + analytic FLOPs).
+                        modeled_phase_s = timeline_lib.model_phase_seconds(
+                            cfg, mesh, batch=args.batch, seq=args.seq,
+                            stage_msg_bytes=stage_msg_bytes)
+                        timeline.set_phase_seconds(modeled_phase_s)
+                    is_straggler = straggler.record(s, dt)
+                    if is_straggler:
+                        obs_events.emit("straggler", step=s, dt=dt,
+                                        ema=straggler.ema,
+                                        factor=args.straggler_factor,
+                                        phases=rec.phase_seconds())
+                    if monitor is not None:
+                        signals = {"step_time": dt, "loss": loss,
+                                   "comm_share": timeline.comm_share(),
+                                   "straggler": 1.0 if is_straggler else 0.0}
+                        if "obs_load_imbalance" in metrics:
+                            signals["load_imbalance"] = float(
+                                metrics["obs_load_imbalance"])
+                        monitor.observe(s, signals)
+                        if escalator is not None and escalator.should_exit:
+                            # persistent degradation: make the run durable and
+                            # hand the restart decision to the supervisor
+                            if mgr:
+                                mgr.save_async(s + 1, state)
+                                mgr.wait()
+                            stop_profile()
+                            export_artifacts(metrics)
+                            return EXIT_WATCHDOG
+                    if rebalancer is not None:
+                        rebalancer.record(np.asarray(metrics["expert_load"]),
+                                          placement)
+                    if s % args.log_every == 0:
+                        comm = ""
+                        if "comm_algorithm" in metrics:
+                            comm = comm_planner.describe_comm_metrics(
+                                int(metrics["comm_algorithm"]),
+                                int(metrics["comm_degraded"]),
+                                int(metrics["comm_calibrated"]),
+                                int(metrics["comm_wire_format"]))
+                        obs_events.emit(
+                            "step", step=s, loss=loss,
+                            ce=float(metrics["ce"]),
+                            lr=float(metrics["lr"]), dt=dt,
+                            skips=int(metrics["grad_skips"]), comm=comm,
+                            comm_share=timeline.comm_share())
+                    want_ckpt = mgr and (s + 1) % args.ckpt_every == 0
+                    if preempt.requested.is_set():
+                        if mgr:
+                            mgr.save_async(s + 1, state)
+                            mgr.wait()
+                        obs_events.emit("preempt", step=s)
+                        stop_profile()
+                        export_artifacts(metrics)
+                        return 42
+                    if want_ckpt:
+                        with TraceAnnotation("checkpoint"):
+                            mgr.save_async(s + 1, state)
+                    if chaos is not None:
+                        chaos.on_step_end(s, manager=mgr, ckpt_dir=args.ckpt)
+                # after the step span closes, so the trace keeps it
                 if profiling:
                     steps_profiled += 1
                     if steps_profiled >= args.profile:
                         stop_profile()
-                is_straggler = straggler.record(s, dt)
-                if is_straggler:
-                    obs_events.emit("straggler", step=s, dt=dt,
-                                    ema=straggler.ema,
-                                    factor=args.straggler_factor,
-                                    phases=rec.phase_seconds())
-                if monitor is not None:
-                    signals = {"step_time": dt, "loss": loss,
-                               "comm_share": timeline.comm_share(),
-                               "straggler": 1.0 if is_straggler else 0.0}
-                    if "obs_load_imbalance" in metrics:
-                        signals["load_imbalance"] = float(
-                            metrics["obs_load_imbalance"])
-                    monitor.observe(s, signals)
-                    if escalator is not None and escalator.should_exit:
-                        # persistent degradation: make the run durable and
-                        # hand the restart decision to the supervisor
-                        if mgr:
-                            mgr.save_async(s + 1, state)
-                            mgr.wait()
-                        stop_profile()
-                        export_artifacts(metrics)
-                        return EXIT_WATCHDOG
-                if rebalancer is not None:
-                    rebalancer.record(np.asarray(metrics["expert_load"]),
-                                      placement)
-                if s % args.log_every == 0:
-                    comm = ""
-                    if "comm_algorithm" in metrics:
-                        comm = comm_planner.describe_comm_metrics(
-                            int(metrics["comm_algorithm"]),
-                            int(metrics["comm_degraded"]),
-                            int(metrics["comm_calibrated"]),
-                            int(metrics["comm_wire_format"]))
-                    obs_events.emit(
-                        "step", step=s, loss=loss,
-                        ce=float(metrics["ce"]),
-                        lr=float(metrics["lr"]), dt=dt,
-                        skips=int(metrics["grad_skips"]), comm=comm,
-                        comm_share=timeline.comm_share())
-                want_ckpt = mgr and (s + 1) % args.ckpt_every == 0
-                if preempt.requested.is_set():
-                    if mgr:
-                        mgr.save_async(s + 1, state)
-                        mgr.wait()
-                    obs_events.emit("preempt", step=s)
-                    stop_profile()
-                    export_artifacts(metrics)
-                    return 42
-                if want_ckpt:
-                    mgr.save_async(s + 1, state)
-                if chaos is not None:
-                    chaos.on_step_end(s, manager=mgr, ckpt_dir=args.ckpt)
             if mgr:
-                mgr.save_async(args.steps, state)
-                mgr.wait()
+                with TraceAnnotation("checkpoint"):
+                    mgr.save_async(args.steps, state)
+                    mgr.wait()
         watchdog.stop()
         obs_events.emit("train_done", steps=args.steps, loss=loss,
                         comm_share=timeline.comm_share(),

@@ -25,6 +25,8 @@ from repro.models import xlstm as xlstm_lib
 from repro.models.layers import (embed, embedding_init, fanin_init, mlp_apply,
                                  mlp_init, rmsnorm, rmsnorm_init, unembed)
 from repro.obs import metrics as obs_metrics
+from repro.obs import tracing as obs_tracing
+from repro.obs.tracing import phase_scope
 from repro.runtime.sharding import constrain
 
 # ---------------------------------------------------------------- helpers --
@@ -130,21 +132,26 @@ def _apply_mixer(p, x, cfg: ModelConfig, mesh, *, causal, kv_chunk,
                  enc_states=None):
     mixer_kind = _infer_mixer_kind(p)
     if mixer_kind == ATTN:
-        y = attn_lib.attention_apply(
-            p["mixer"], x, num_heads=cfg.num_heads,
-            num_kv_heads=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
-            rope_theta=cfg.rope_theta, causal=causal, kv_chunk=kv_chunk,
-            use_rope=(cfg.pos_emb == "rope"), mesh=mesh)
-        if enc_states is not None and "cross" in p:
-            xc = x + y
-            y2 = attn_lib.attention_apply(
-                p["cross"], rmsnorm(p["cross_norm"], xc, cfg.norm_eps),
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+        # the whole attention mixer: projections, the sequence<->head
+        # exchanges it issues, and cross-attention where there is one
+        with phase_scope(obs_tracing.PH_ATTENTION):
+            y = attn_lib.attention_apply(
+                p["mixer"], x, num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                causal=False, kv_chunk=kv_chunk, use_rope=False,
-                kv_x=enc_states, mesh=mesh)
-            return y + y2
-        return y
+                causal=causal, kv_chunk=kv_chunk,
+                use_rope=(cfg.pos_emb == "rope"), mesh=mesh)
+            if enc_states is not None and "cross" in p:
+                xc = x + y
+                y2 = attn_lib.attention_apply(
+                    p["cross"], rmsnorm(p["cross_norm"], xc, cfg.norm_eps),
+                    num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                    head_dim=cfg.resolved_head_dim,
+                    rope_theta=cfg.rope_theta, causal=False,
+                    kv_chunk=kv_chunk, use_rope=False, kv_x=enc_states,
+                    mesh=mesh)
+                return y + y2
+            return y
     if mixer_kind == MAMBA:
         return ssm_lib.mamba_apply(p["mixer"], x, cfg.ssm, cfg.norm_eps,
                                    mesh=mesh)
@@ -332,13 +339,14 @@ def head_logits(params, cfg: ModelConfig, mesh, x: jax.Array) -> jax.Array:
     """Final norm + (tied) unembedding -> vocab-sharded f32 logits.
     ``params`` needs "final_norm" and "embed"/"head" only — the last
     pipeline stage calls this with just its own slice."""
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    x = constrain(x, mesh, "batch", "seq", None)
-    if cfg.tie_embeddings:
-        logits = unembed(params["embed"], x)
-    else:
-        logits = (x @ params["head"]["w"]).astype(jnp.float32)
-    return constrain(logits, mesh, "batch", None, "vocab")
+    with phase_scope(obs_tracing.PH_LM_HEAD):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        x = constrain(x, mesh, "batch", "seq", None)
+        if cfg.tie_embeddings:
+            logits = unembed(params["embed"], x)
+        else:
+            logits = (x @ params["head"]["w"]).astype(jnp.float32)
+        return constrain(logits, mesh, "batch", None, "vocab")
 
 
 def forward(params, cfg: ModelConfig, mesh: Mesh, batch: Dict, *,
@@ -360,6 +368,12 @@ def loss_from_logits(cfg: ModelConfig, logits: jax.Array, stats: Dict,
                      batch: Dict) -> Tuple[jax.Array, Dict]:
     """CE + z-loss + MoE aux from already-computed logits — the tail the
     last pipeline stage shares with the monolithic ``loss_fn``."""
+    with phase_scope(obs_tracing.PH_LM_HEAD):
+        return _loss_from_logits(cfg, logits, stats, batch)
+
+
+def _loss_from_logits(cfg: ModelConfig, logits: jax.Array, stats: Dict,
+                      batch: Dict) -> Tuple[jax.Array, Dict]:
     labels = batch["labels"]
     if cfg.frontend == "patch_stub" and "patch_embeds" in batch:
         npatch = batch["patch_embeds"].shape[1]

@@ -19,18 +19,21 @@ events, correlated with the ``obs/tracing.py`` named scopes:
    ``hlo_phase_map(compiled_text)`` recovers instruction -> phase from
    the compiled HLO text (the launcher lowers the train step once when
    profiling), and the parser joins trace events against it.
- * **Collectives** lose their scope in SPMD partitioning (the
-   partitioner re-attributes their op_name metadata to neighboring
-   ops), so they are classified structurally by opcode: ``all-to-all``
-   events ARE the MoE exchange — their time is split evenly between the
+ * **Collectives** the program issues itself carry their scope (the
+   MoE exchange's legs, the attention's exchanges); the ones SPMD
+   partitioning inserts or re-attributes may carry none, and those are
+   classified structurally by opcode: an unscoped ``all-to-all`` is
+   taken for the MoE exchange — its time is split evenly between the
    ``dispatch_a2a`` / ``combine_a2a`` legs (the legs carry symmetric
    payloads, and their SUM — the comm share — is the number that
    matters); ``collective-permute`` is the pipeline ``stage_transfer``
    hop.  Grad all-reduces and resharding all-gathers stay in ``other``:
    they are comm, but not the paper's a2a phases.
 
-Device events of the profiled module that match no phase land in
-``other``; events of *other* modules (init, eval jits) are excluded when
+The step-level scopes (``attention``, ``lm_head``, ``optimizer``) are
+measured the same way and reported beside the MoE phases.  Device
+events of the profiled module that match no scope land in ``other``;
+events of *other* modules (init, eval jits) are excluded when
 the module is known, so the measurement is the train step's.  Durations
 are summed per phase across the whole capture and divided by the number
 of profiled steps and participating devices — the result has the same
@@ -48,13 +51,21 @@ from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Tuple
 
 from repro.obs import timeline as timeline_lib
+from repro.obs import tracing as tracing_lib
 from repro.obs.timeline import PHASE_ORDER, PhaseSpan, StepRecord
 
 OTHER = "other"
 
+# The step-level scopes (attention, LM head, optimizer): measured like the
+# MoE phases, reported after them.  The cost model prices them as "other"
+# (obs/reconcile.py folds them back before comparing).
+STEP_PHASES = tuple(s[len(tracing_lib.PREFIX):]
+                    for s in tracing_lib.STEP_SCOPES)
+MEASURED_ORDER = PHASE_ORDER[:-1] + STEP_PHASES + (OTHER,)
+
 # "obs/<phase>" anywhere in an op path / scope string.
-_PHASE_NAMES = tuple(p for p in PHASE_ORDER if p != OTHER)
-PHASE_RE = re.compile("obs/(%s)" % "|".join(_PHASE_NAMES))
+_PHASE_NAMES = tuple(p for p in MEASURED_ORDER if p != OTHER)
+PHASE_RE = re.compile(r"obs/(%s)\b" % "|".join(_PHASE_NAMES))
 
 # One post-optimization HLO instruction with op metadata:
 #   %name.0 = f32[...] op(...), ..., metadata={op_name="jit(f)/.../obs/gate/mul" ...}
@@ -214,7 +225,7 @@ class MeasuredTimeline:
             "measured_step_s": self.step_seconds(),
             "measured_comm_share": self.comm_share(),
         }
-        for name in PHASE_ORDER:
+        for name in MEASURED_ORDER:
             if name in self.phase_seconds:
                 out[f"measured_{name}_s"] = self.phase_seconds[name]
         return out
@@ -230,7 +241,7 @@ def _synth_records(phase_seconds: Dict[str, float], steps: int
     for s in range(max(1, steps)):
         spans: List[PhaseSpan] = []
         start = t
-        for name in PHASE_ORDER:
+        for name in MEASURED_ORDER:
             d = phase_seconds.get(name, 0.0)
             if d > 0.0:
                 spans.append(PhaseSpan(name, t, d))

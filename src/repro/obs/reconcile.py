@@ -131,7 +131,13 @@ def reconcile(modeled: Dict[str, float], measured: Dict[str, float], *,
               stale_threshold: float = STALE_THRESHOLD) -> DriftReport:
     """Per-phase modeled-vs-measured error over the union of phases,
     share-weighted into one drift score (and a comm-only score that
-    drives the stale-calibration recommendation)."""
+    drives the stale-calibration recommendation).  Measured phases the
+    model does not price (the step-level scopes) count as "other"."""
+    folded: Dict[str, float] = {}
+    for name, v in measured.items():
+        key = name if name in PHASE_ORDER else "other"
+        folded[key] = folded.get(key, 0.0) + v
+    measured = folded
     m_share = _shares(modeled)
     x_share = _shares(measured)
     phases = []

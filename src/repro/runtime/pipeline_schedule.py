@@ -352,8 +352,9 @@ def make_pipeline_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
 
     def train_step(state, batch):
         batch, chaos_scale = split_chaos_scale(batch)
-        l, metrics, grads = grad_fn(state.params, batch)
-        l = apply_chaos_scale(l, chaos_scale)
-        return apply_gradients(state, opt_cfg, l, metrics, grads)
+        with obs_tracing.activate(cfg.moe.obs.phase_tracing):
+            l, metrics, grads = grad_fn(state.params, batch)
+            l = apply_chaos_scale(l, chaos_scale)
+            return apply_gradients(state, opt_cfg, l, metrics, grads)
 
     return train_step

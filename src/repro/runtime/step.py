@@ -10,6 +10,7 @@ from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig, OptimizerConfig
 from repro.models import model as model_lib
+from repro.obs import tracing as obs_tracing
 from repro.optim.adam import OptState, adamw_init, adamw_update
 from repro.optim.schedule import warmup_cosine
 
@@ -58,11 +59,12 @@ def apply_gradients(state: TrainState, opt_cfg: OptimizerConfig, l, metrics,
     """Shared optimizer tail (lr schedule, NaN-skip, adamw) — used by the
     monolithic step below and the 1F1B pipeline step
     (runtime/pipeline_schedule.py)."""
-    lr = warmup_cosine(state.opt.step, opt_cfg.lr, opt_cfg.warmup_steps,
-                       opt_cfg.total_steps)
-    skip = ~jnp.isfinite(l)
-    new_params, new_opt = adamw_update(state.params, grads, state.opt,
-                                       opt_cfg, lr, skip=skip)
+    with obs_tracing.phase_scope(obs_tracing.PH_OPTIMIZER):
+        lr = warmup_cosine(state.opt.step, opt_cfg.lr, opt_cfg.warmup_steps,
+                           opt_cfg.total_steps)
+        skip = ~jnp.isfinite(l)
+        new_params, new_opt = adamw_update(state.params, grads, state.opt,
+                                           opt_cfg, lr, skip=skip)
     metrics = dict(metrics, lr=lr, grad_skips=new_opt.grad_skips)
     return TrainState(new_params, new_opt), metrics
 
@@ -80,6 +82,10 @@ def make_accum_grad_fn(cfg: ModelConfig, mesh: Mesh, *,
     grad_fn = jax.value_and_grad(loss, has_aux=True, allow_int=True)
 
     def accum_grads(params, batch):
+        with obs_tracing.activate(cfg.moe.obs.phase_tracing):
+            return _accum_grads(params, batch)
+
+    def _accum_grads(params, batch):
         if not microbatch:
             (l, metrics), grads = grad_fn(params, batch)
             return l, metrics, grads
@@ -146,9 +152,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, mesh: Mesh,
 
     def train_step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
         batch, chaos_scale = split_chaos_scale(batch)
-        l, metrics, grads = accum_grads(state.params, batch)
-        l = apply_chaos_scale(l, chaos_scale)
-        return apply_gradients(state, opt_cfg, l, metrics, grads)
+        with obs_tracing.activate(cfg.moe.obs.phase_tracing):
+            l, metrics, grads = accum_grads(state.params, batch)
+            l = apply_chaos_scale(l, chaos_scale)
+            return apply_gradients(state, opt_cfg, l, metrics, grads)
 
     return train_step
 
@@ -195,11 +202,12 @@ def _make_dp_only_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
 
         bspec = {k: bspec_for(v) for k, v in batch.items()}
         rep = jax.tree.map(lambda _: P(), state.params)
-        l, metrics, grads = shard_map(
-            local_step, mesh=mesh, in_specs=(rep, bspec),
-            out_specs=(P(), P(), P()))(state.params, batch)
-        l = apply_chaos_scale(l, chaos_scale)
-        return apply_gradients(state, opt_cfg, l, metrics, grads)
+        with obs_tracing.activate(cfg.moe.obs.phase_tracing):
+            l, metrics, grads = shard_map(
+                local_step, mesh=mesh, in_specs=(rep, bspec),
+                out_specs=(P(), P(), P()))(state.params, batch)
+            l = apply_chaos_scale(l, chaos_scale)
+            return apply_gradients(state, opt_cfg, l, metrics, grads)
 
     return train_step
 

@@ -513,3 +513,86 @@ def test_train_launcher_writes_artifacts_8dev(tmp_path):
     assert m["obs_wire_bytes"] > 0.0
     assert m["obs_compression_rate"] == pytest.approx(
         m["obs_wire_bytes"] / m["obs_raw_bytes"])
+
+
+@pytest.mark.parametrize("wire", ["bf16", "int8"])
+@pytest.mark.parametrize("transport", ["flat", "hierarchical", "pipelined"])
+def test_step_scopes_and_exchange_legs_in_compiled_hlo_4dev(transport,
+                                                           wire):
+    """The train step lowered with ObsConfig on, on a 1x4 (data x model)
+    mesh like the four-chip benchmark cell, LSH on and off: the
+    step-level scopes land in the compiled HLO's op_name metadata; every
+    all-to-all sits under one exchange leg (or the attention or LM head
+    that issues it) on each transport and wire format; no instruction
+    sits under two scopes; and the dispatch scatter runs under obs/gate
+    (inside the dispatch leg where the quantized wire fuses it)."""
+    out = _run(f"""
+        import dataclasses, os, re
+        os.environ["REPRO_KERNEL_BACKEND"] = "pallas_interpret"
+        import jax, jax.numpy as jnp
+        from repro.compat import set_mesh
+        from repro.configs.base import ObsConfig, OptimizerConfig
+        from repro.configs.registry import get_smoke_config
+        from repro.launch import mesh as mesh_lib
+        from repro.obs import events as events_lib
+        from repro.obs import tracing
+        from repro.runtime.step import init_train_state, make_train_step
+
+        transport, wire = {transport!r}, {wire!r}
+        base = get_smoke_config("granite-moe-3b-a800m")
+        comm = dataclasses.replace(
+            base.moe.comm, a2a_impl=transport,
+            node_size=2 if transport == "hierarchical" else 0,
+            overlap_chunks=2 if transport == "pipelined" else 1)
+        cfg = base.replace(moe=dataclasses.replace(
+            base.moe, obs=ObsConfig(enabled=True), comm=comm,
+            lsh=dataclasses.replace(base.moe.lsh, wire_format=wire)))
+        mesh = mesh_lib.make_host_mesh(1, 1, 4)
+        opt = OptimizerConfig()
+        plans = events_lib.MemorySink()
+        events_lib.global_log().add_sink(plans)
+        legs = {{tracing.PH_DISPATCH, tracing.PH_COMBINE}}
+        issuers = legs | {{tracing.PH_ATTENTION, tracing.PH_LM_HEAD}}
+        for use_lsh in (True, False):
+            with set_mesh(mesh):
+                state = jax.eval_shape(lambda: init_train_state(
+                    jax.random.PRNGKey(0), cfg, opt, mesh))
+                b = jax.ShapeDtypeStruct((8, 32), jnp.int32)
+                hlo = jax.jit(make_train_step(cfg, opt, mesh,
+                                              use_lsh=use_lsh)).lower(
+                    state, {{"tokens": b, "labels": b}}).compile().as_text()
+            assert {{e.data["algorithm"] for e in
+                    plans.of_kind("comm_plan")}} == {{transport}}
+            names = re.findall(r'op_name="([^"]*)"', hlo)
+            scoped = [set(re.findall(r"obs/[a-z0-9_]+", n)) for n in names]
+            for s in tracing.STEP_SCOPES:
+                assert any(s in sc for sc in scoped), s
+            assert all(len(sc) <= 1 for sc in scoped), \\
+                [n for n, sc in zip(names, scoped) if len(sc) > 1][:3]
+            a2a = [set(re.findall(r"obs/[a-z0-9_]+", line))
+                   for line in hlo.splitlines() if " all-to-all(" in line]
+            assert a2a and all(len(sc & issuers) == 1 for sc in a2a), a2a
+            assert {{s for sc in a2a for s in sc}} >= legs
+            # forward and rematerialised calls of a kernel, by their full
+            # op_name (the interpreter's inner loops carry relative ones);
+            # the backward calls are transposes (the combine gather's)
+            def calls(kernel):
+                return [sc for n, sc in zip(names, scoped)
+                        if n.startswith("jit(train_step)")
+                        and "/jit(" + kernel + ")" in n
+                        and ("transpose(" not in n
+                             or "rematted_computation" in n)]
+            fused = wire == "int8" and transport != "pipelined" \\
+                and not use_lsh
+            scatter = calls("dispatch_scatter_pallas")
+            if fused:
+                assert not scatter
+                fused_scatter = calls("dispatch_scatter_quantize_pallas")
+                assert fused_scatter and all(
+                    sc == {{tracing.PH_DISPATCH}} for sc in fused_scatter)
+            else:
+                assert scatter and all(sc == {{tracing.PH_GATE}}
+                                       for sc in scatter), scatter
+        print("SCOPES", transport, wire)
+    """, devices=4)
+    assert f"SCOPES {transport} {wire}" in out

@@ -10,6 +10,7 @@ stale-calibration -> re-probe loop through the tune cache.  Subprocess:
 a real ``--profile`` train run on 2 forced host devices must produce a
 MeasuredTimeline (not a cost-model attribution), and the bench harness
 must append trajectory rows and gate clean."""
+import glob
 import gzip
 import json
 import math
@@ -552,6 +553,45 @@ def test_train_profile_writes_measured_timeline_2dev(tmp_path):
     evs = events_lib.read_jsonl(os.path.join(mdir, "events.jsonl"))
     drift = [e for e in evs if e.kind == "model_drift"]
     assert any(e.data["phase"] == "*" for e in drift)
+
+
+def test_train_profile_host_spans_and_step_scopes_2dev(tmp_path):
+    """--profile 2 over four smoke steps with a checkpoint: the launcher's
+    host spans (one step span per step, ``batch``, ``dispatch``, ``wait``
+    and ``checkpoint``) sit on the trace's /host:CPU plane, and the
+    measured timeline reports the step-level scopes."""
+    from jax.profiler import ProfileData
+
+    mdir = str(tmp_path / "obs")
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=_SRC)
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--arch",
+         "granite-moe-3b-a800m", "--smoke", "--steps", "4", "--batch",
+         "4", "--seq", "32", "--mesh-model", "2", "--log-every", "1",
+         "--metrics-dir", mdir, "--profile", "2",
+         "--ckpt", str(tmp_path / "ckpt"), "--ckpt-every", "2"],
+        capture_output=True, text=True, env=env, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+
+    pb = profile_lib.find_trace_file(os.path.join(mdir, "jax_trace"))
+    pb = glob.glob(os.path.join(os.path.dirname(pb), "*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(pb[0]).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    spans[e.name] = spans.get(e.name, 0) + 1
+    # steps 1 and 2 profiled; the checkpoint after step 1
+    for name in ("train", "batch", "dispatch", "wait"):
+        assert spans.get(name) == 2, (name, spans.get(name))
+    assert spans.get("checkpoint") == 1
+
+    with open(os.path.join(mdir, "metrics.json")) as f:
+        m = json.load(f)
+    for phase in profile_lib.STEP_PHASES:
+        assert m[f"measured_{phase}_s"] > 0.0, phase
 
 
 def test_bench_harness_trajectory_and_gate_2dev(tmp_path):
