@@ -24,14 +24,21 @@ exists tile-locally in VMEM:
                               with WireCodec.decode).
 
 Bit-identity contract (docs/kernels.md): each op computes EXACTLY the
-composition of its unfused parts — same selection masks, same tile
-accumulation order, same po2 scale arithmetic — so fused and composed
-paths agree bit-for-bit on every backend, values and (through the
-composite VJPs in comm/wire.py) gradients.
+composition of its unfused parts, so fused and composed paths agree
+bit-for-bit on every backend, values and (through the composite VJPs in
+comm/wire.py) gradients.  The routing twins keep a one-hot MXU
+contraction while the unfused routing ops move rows by index
+(kernels/scatter_gather.py); they agree because each gathered output
+has one nonzero term, and both add colliding scatter entries in entry
+order (along the sequential token-tile axis here).  The po2 scale
+arithmetic is shared with kernels/wire_quant.py.  The interpret-mode
+suite checks the contract; on a v5e, Mosaic's default precision rounds
+the f32 operands of the one-hot contraction to bf16, so there the twins
+differ from the exact row moves by that rounding.
 
-Grids match the unfused kernels: scatter-quantize (E, C/tile_c, F/tile_t)
-with a [tile_c, H] f32 VMEM scratch accumulator; dequant-gather
-(F/tile_t, E, C/tile_c); dequant-residual (G, C/tile_t).
+Grids: scatter-quantize (E, C/tile_c, F/tile_t) with a [tile_c, H] f32
+VMEM scratch accumulator; dequant-gather (F/tile_t, E, C/tile_c);
+dequant-residual (G, C/tile_t).
 """
 from __future__ import annotations
 
@@ -42,8 +49,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.scatter_gather import capacity_tile, dot_tn, sel_mask
+from repro.kernels.residual_apply import dot_tn
+from repro.kernels.scatter_gather import capacity_tile
 from repro.kernels.wire_quant import _encode, po2_scale, qmax, quant_dtype
+
+
+def sel_mask(ids, pos, expert, row0, rows):
+    """[rows, tile_t] selection mask between buffer rows [row0, row0 +
+    rows) of ``expert`` and a token tile: pos one-hot AND id match, as
+    one 2-D int compare.  ids/pos: [1, tile_t] (tokens along lanes).  The
+    gather direction contracts it transposed (``dot_tn``)."""
+    own = jnp.where(ids == expert, pos - row0, -1)         # [1, tile_t]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (rows, ids.shape[1]), 0)
+    return (iota == own).astype(jnp.float32)
 
 
 # ------------------------------------------- scatter + quantize (fused) --

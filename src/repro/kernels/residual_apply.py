@@ -16,7 +16,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.scatter_gather import dot_tn
+
+def dot_tn(a, b):
+    """a^T @ b on the MXU: a [K, M], b [K, N] -> [M, N] f32.  Shared with
+    the fused codec kernels (kernels/fused_wire.py)."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(slots_ref, eout_ref, resid_ref, out_ref, *, num_slots):

@@ -131,17 +131,38 @@ def test_compress_gradient_parity(rng):
                                atol=1e-4)
 
 
-def _routing_inputs(rng, f=300, e=5, c=16, h=32):
+def _routing_inputs(rng, f=300, e=5, c=16, h=32, dtype=jnp.float32,
+                    unused_expert=False, drop=None):
     """Flattened routing ids incl. out-of-range entries, plus src/weights.
-    f=300 crosses the kernels' 128 tile boundary."""
-    ids = jax.random.randint(rng, (f,), 0, e).astype(jnp.int32)
-    ids = ids.at[3].set(-1).at[60].set(e + 2)      # overflow-bin entries
+    f=300 crosses the kernels' 128-entry tile.  ``unused_expert`` routes
+    nothing to the last expert, ``drop=(a, b)`` sends entries a..b-1 to
+    the overflow bin."""
+    ids = jax.random.randint(rng, (f,), 0, e - unused_expert)
+    ids = ids.astype(jnp.int32).at[3].set(-1).at[60].set(e + 2)
     pos, keep, _ = dispatch.positions_in_expert(ids, e, c,
                                                 backend="reference")
     flat_ids = jnp.where(keep, ids, e)
-    src = jax.random.normal(jax.random.fold_in(rng, 1), (f, h), jnp.float32)
+    if drop is not None:
+        flat_ids = flat_ids.at[drop[0]:drop[1]].set(e)
+    src = jax.random.normal(jax.random.fold_in(rng, 1), (f, h),
+                            jnp.float32).astype(dtype)
     w = jax.random.uniform(jax.random.fold_in(rng, 2), (f,), jnp.float32)
     return flat_ids, pos, src, w, e, c
+
+
+# Routing shapes for the row-moving kernels (kernels/scatter_gather.py).
+# At h=32 an entry tile is 128 entries and a DMA block 8 rows (f32) or
+# 16 (bf16).
+ROUTING_CASES = {
+    "base": {},
+    # F = 203 and E·C = 39: neither a whole number of entry tiles nor of
+    # 8- or 16-row blocks
+    "ragged": dict(f=203, e=3, c=13),
+    "empty_expert": dict(unused_expert=True),
+    # the second entry tile, entries 128..255, dropped whole
+    "dropped_tile": dict(drop=(128, 256)),
+    "bf16": dict(dtype=jnp.bfloat16),
+}
 
 
 def test_positions_in_expert_parity(rng):
@@ -157,15 +178,17 @@ def test_positions_in_expert_parity(rng):
     assert int(outs["reference"][2].sum()) == 298
 
 
-def test_dispatch_scatter_combine_gather_parity(rng):
+@pytest.mark.parametrize("case", list(ROUTING_CASES))
+def test_dispatch_scatter_combine_gather_parity(rng, case):
     """Values bit-for-bit across backends for both routing directions."""
-    flat_ids, pos, src, w, e, c = _routing_inputs(rng)
+    flat_ids, pos, src, w, e, c = _routing_inputs(rng,
+                                                  **ROUTING_CASES[case])
     bufs = {b: dispatch.dispatch_scatter(flat_ids, pos, src, e, c, backend=b)
             for b in BACKENDS}
     np.testing.assert_array_equal(np.asarray(bufs["reference"]),
                                   np.asarray(bufs["pallas_interpret"]))
-    outs = {b: dispatch.combine_gather(flat_ids, pos, bufs["reference"], w,
-                                       backend=b)
+    gbuf = bufs["reference"].astype(src.dtype)
+    outs = {b: dispatch.combine_gather(flat_ids, pos, gbuf, w, backend=b)
             for b in BACKENDS}
     np.testing.assert_array_equal(np.asarray(outs["reference"]),
                                   np.asarray(outs["pallas_interpret"]))
@@ -174,12 +197,17 @@ def test_dispatch_scatter_combine_gather_parity(rng):
     assert dropped.any()
     np.testing.assert_array_equal(
         np.asarray(outs["reference"])[dropped], 0.0)
+    if case == "empty_expert":
+        np.testing.assert_array_equal(
+            np.asarray(bufs["pallas_interpret"])[e - 1], 0.0)
 
 
-def test_routing_gradient_parity(rng):
+@pytest.mark.parametrize("case", list(ROUTING_CASES))
+def test_routing_gradient_parity(rng, case):
     """The custom VJPs (reference and Pallas both use the mutual-transpose
     backward structure) must agree bit-for-bit on d_src, d_buf, d_w."""
-    flat_ids, pos, src, w, e, c = _routing_inputs(rng)
+    flat_ids, pos, src, w, e, c = _routing_inputs(rng,
+                                                  **ROUTING_CASES[case])
 
     def f(src, w, backend):
         buf = dispatch.dispatch_scatter(flat_ids, pos, src, e, c,
@@ -193,7 +221,7 @@ def test_routing_gradient_parity(rng):
     for i, name in enumerate(("d_src", "d_weights")):
         a = np.asarray(grads["reference"][i])
         b = np.asarray(grads["pallas_interpret"][i])
-        assert np.abs(a).sum() > 0, name
+        assert np.abs(a.astype(np.float32)).sum() > 0, name
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
