@@ -1,4 +1,7 @@
-"""Work counts kept with the benchmark: chip peaks and model FLOPs.
+"""Work counts kept with the benchmark: chip peaks and kernel bytes.
+
+A model's FLOPs per trained token are its model module's
+(``models/<name>.py``, ``train_flops_per_token``).
 
 A kernel call's bytes are its operands' and results' (``array_bytes``;
 ``trace.Op.interface_bytes`` reads the same from a traced call's
@@ -6,7 +9,7 @@ shapes).
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple
+from typing import NamedTuple
 
 
 class Peaks(NamedTuple):
@@ -41,26 +44,3 @@ def array_bytes(*avals) -> int:
     return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
                for a in avals)
 
-
-def active_matmul_params(m: Dict) -> int:
-    """Weights one token multiplies by in a forward pass: attention
-    projections, router, its top-k experts' SwiGLU matrices and the LM
-    head, over every layer.  The embedding is a lookup and is left out."""
-    h, dh = m["hidden_size"], m["head_dim"]
-    q, kv = m["num_attention_heads"] * dh, m["num_key_value_heads"] * dh
-    attn = h * q + 2 * h * kv + q * h
-    router = h * m["num_local_experts"]
-    experts = m["num_experts_per_tok"] * 3 * h * m["intermediate_size"]
-    return m["num_hidden_layers"] * (attn + router + experts) \
-        + h * m["vocab_size"]
-
-
-def train_flops_per_token(m: Dict, seq_len: int) -> float:
-    """Model FLOPs of one trained token: 6 per active weight (forward and
-    backward) plus causal attention, 6 * layers * heads * head_dim * S
-    (QK^T and PV over half the sequence on average, times 3).  Every
-    token counts its full top-k, with or without LSH; LSH's own work and
-    recomputation do not count."""
-    attn = 6 * m["num_hidden_layers"] * m["num_attention_heads"] \
-        * m["head_dim"] * seq_len
-    return 6.0 * active_matmul_params(m) + attn

@@ -2,9 +2,12 @@
 
 A cell is a ``workloads`` entry of ``BENCHMARK.json``: a configuration
 file (``configs/<name>.json``), a traffic mix (``traffic/<name>.json``)
-and a chip count.  Its per-layer metrics are the ``per_layer`` entries
-that list it; each is read by ``metrics/<name>.py``.  Nothing here names
-a cell, a configuration or a metric.
+and a chip count.  The configuration file names its model module
+(``"model"``: ``models/<module>.py``), which gives the plain reference,
+the FLOP count and the program's keys.  Its per-layer metrics are the
+``per_layer`` entries that list it; each is read by
+``metrics/<name>.py``.  Nothing here names a cell, a configuration, an
+architecture or a metric.
 
 Set-up builds the program's compiled train step (state donated) and its
 state around the benchmark's seeded weights, then drives it through the
@@ -56,7 +59,8 @@ def cell_spec(workload: str, root: Path = ROOT) -> SimpleNamespace:
     if workload not in cells:
         raise Refused(f"no workload {workload!r}; known: {sorted(cells)}")
     w = cells[workload]
-    conf = load_json(root / HERE.name / "configs" / f"{w['config']}.json")
+    conf_path = root / HERE.name / "configs" / f"{w['config']}.json"
+    conf = load_json(conf_path)
     mix = load_json(root / HERE.name / "traffic" / f"{w['traffic']}.json")
     moves = {m["name"] for m in bench["end_to_end"]
              if workload in m.get("workloads", [workload])}
@@ -64,10 +68,34 @@ def cell_spec(workload: str, root: Path = ROOT) -> SimpleNamespace:
                  if workload in m.get("workloads", [workload])
                  and m["moves"] in moves]
     return SimpleNamespace(name=workload, chips=int(w["chips"]), conf=conf,
+                           model=model_module(conf, conf_path,
+                                              root / HERE.name / "models"),
                            metrics_dir=root / HERE.name / "metrics",
                            mix=mix, end_to_end=[m for m in bench["end_to_end"]
                                                 if m["name"] in moves],
                            per_layer=per_layer)
+
+
+def load_file(path: Path, name: str):
+    """The Python module in ``path``, imported under ``name``."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def model_module(conf: Dict, conf_path: Path, models_dir: Path):
+    """The model module a configuration file names (``"model"``); a file
+    that names none, or a module that does not exist, is refused."""
+    name = conf.get("model")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise Refused(f"{conf_path} names no model module "
+                      f"(\"model\": {name!r})")
+    path = models_dir / f"{name}.py"
+    if not path.is_file():
+        raise Refused(f"{conf_path} names the model module {name!r}, and "
+                      f"there is no {path}")
+    return load_file(path, f"chipbench.models.{name}")
 
 
 def seed_key(seed: int):
@@ -108,18 +136,18 @@ class Trainer:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        conf, mix = spec.conf, spec.mix
+        conf, mix, self.model = spec.conf, spec.mix, spec.model
         program.import_program()
-        self.cfg = program.model_config(conf, phases=phases)
+        self.cfg = program.model_config(conf, self.model, phases=phases)
         self.opt = program.optimizer_config(conf)
         self.mesh = program.mesh(conf)
-        self.dims = reference.dims_from_config(conf, use_lsh=mix["use_lsh"])
+        self.dims = self.model.dims(conf, use_lsh=mix["use_lsh"])
         self.adam = reference.adam_from_config(conf)
         self.groups = conf["mesh"]["model"]
-        dims, opt = self.dims, self.opt
+        dims, opt, init_params = self.dims, self.opt, self.model.init_params
         with program.set_mesh(self.mesh):
             abstract = program.abstract_state(self.cfg, opt, self.mesh)
-            ours = jax.eval_shape(lambda k: reference.init_params(k, dims),
+            ours = jax.eval_shape(lambda k: init_params(k, dims),
                                   seed_key(0))
             if jax.tree.structure(ours) != jax.tree.structure(
                     abstract.params) or any(
@@ -138,14 +166,14 @@ class Trainer:
                                             "labels": b}).compile()
         state_sh, self.batch_sharding = self.step.input_shardings[0]
         self.init = jax.jit(
-            lambda key: program.make_state(reference.init_params(key, dims),
-                                           opt), out_shardings=state_sh)
+            lambda key: program.make_state(init_params(key, dims), opt),
+            out_shardings=state_sh)
         b1 = self.adam.b1
         self.grad_norms = jax.jit(
             lambda m: reference.float_leaf_norms(m) / (1.0 - b1))
         self.change_norms = jax.jit(
             lambda params, key: reference.change_norms(
-                params, reference.init_params(key, dims)))
+                params, init_params(key, dims)))
         self.leaves = reference.leaf_names(ours)
 
     def put(self, batches: List[Dict]) -> List[Dict]:
@@ -193,8 +221,8 @@ class Trainer:
         its weights spread over the cell's chips.  ``precision`` and
         ``fault`` select the control and the planted faults
         (``chipbench/calibrate.py``)."""
-        return reference.run(seed_key(seed), batches, self.dims, self.adam,
-                             self.groups, steps=len(batches),
+        return reference.run(self.model, seed_key(seed), batches, self.dims,
+                             self.adam, self.groups, steps=len(batches),
                              precision=precision, fault=fault,
                              keep_moment=keep_moment,
                              devices=list(self.mesh.devices.flat))
@@ -219,12 +247,8 @@ def window(trainer: Trainer, state, batches: List[Dict]):
 
 
 def metric_reader(metrics_dir: Path, name: str):
-    path = metrics_dir / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(metrics_dir / f"{name}.py",
+                     f"chipbench.metrics.{name}").read
 
 
 def check_lines(gaps: Dict[str, float], limits: Dict[str, float]) -> Dict:
@@ -346,7 +370,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                        recursive=True)[0]
         meta = {"steps": n, "tokens": tokens, "chips": len(used),
                 "device_kind": kind,
-                "flops_per_token": counts.train_flops_per_token(
+                "flops_per_token": spec.model.train_flops_per_token(
                     conf["config"], mix["seq_len"])}
         if keep_trace:
             keep(pb, hlo, meta, Path(keep_trace))

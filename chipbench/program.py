@@ -40,40 +40,13 @@ def import_program():
                       f"{exc}") from None
 
 
-# configuration key -> how the program's ModelConfig states it
-_MODEL_KEYS = {
-    "hidden_size": lambda c: c.d_model,
-    "num_attention_heads": lambda c: c.num_heads,
-    "num_key_value_heads": lambda c: c.num_kv_heads,
-    "head_dim": lambda c: c.resolved_head_dim,
-    "intermediate_size": lambda c: c.moe.expert_ffn_dim,
-    "num_local_experts": lambda c: c.moe.num_experts,
-    "num_experts_per_tok": lambda c: c.moe.top_k,
-    "vocab_size": lambda c: c.vocab_size,
-    "num_hidden_layers": lambda c: c.num_layers,
-    "rope_theta": lambda c: c.rope_theta,
-    "rms_norm_eps": lambda c: c.norm_eps,
-    "tie_word_embeddings": lambda c: c.tie_embeddings,
-    "capacity_factor": lambda c: c.moe.capacity_factor,
-    "router_aux_loss_coef": lambda c: c.moe.router_aux_weight,
-    "router_z_loss_coef": lambda c: c.moe.router_z_weight,
-    "z_loss_coef": lambda c: c.z_loss_weight,
-    "hidden_act": lambda c: {"swiglu": "silu"}.get(c.mlp_act, c.mlp_act),
-    "torch_dtype": lambda c: c.dtype,
-    # the program has no such fields: a plain transformer's values
-    "embedding_multiplier": lambda c: getattr(c, "embedding_multiplier", 1.0),
-    "attention_multiplier": lambda c: getattr(
-        c, "attention_multiplier", c.resolved_head_dim ** -0.5),
-    "residual_multiplier": lambda c: getattr(c, "residual_multiplier", 1.0),
-    "logits_scaling": lambda c: getattr(c, "logits_scaling", 1.0),
-}
 _LSH_KEYS = ("hash_type", "num_hashes", "rotation_dim", "compression_rate",
              "wire_format", "wire_dtype", "error_compensation")
 
 
-def _mismatches(cfg, conf: Dict):
+def _mismatches(cfg, conf: Dict, program_keys: Dict):
     out = []
-    for key, get in _MODEL_KEYS.items():
+    for key, get in program_keys.items():
         if key not in conf["config"]:
             continue
         try:
@@ -91,26 +64,11 @@ def _mismatches(cfg, conf: Dict):
     return out
 
 
-def _set_layers(cfg, n):
-    if n % len(cfg.layout):
-        raise Refused(f"{n} layers is no whole number of "
-                      f"{len(cfg.layout)}-block super-blocks")
-    return cfg.replace(num_super_blocks=n // len(cfg.layout))
-
-
-# configuration keys a cut may change, and how the program's config takes
-# them; every other key is only checked
-_CUTS = {
-    "num_hidden_layers": _set_layers,
-    "num_local_experts": lambda c, n: c.replace(
-        moe=dataclasses.replace(c.moe, num_experts=n)),
-}
-
-
-def model_config(conf: Dict, *, phases: bool):
+def model_config(conf: Dict, model, *, phases: bool):
     """The program's config for this configuration: its registered arch
     (``program.preset``: "full", or "smoke" for the CPU tests) with the
-    file's cuts applied (the keys of ``reduced`` that a cut can change).
+    file's cuts applied (the keys of ``reduced`` that the model module's
+    ``CUTS`` can change), checked against its ``PROGRAM_KEYS``.
     ``phases`` turns on the ``obs/`` phase scopes (HLO metadata only).
     Refuses where any other size or LSH parameter the file states differs
     from the program's."""
@@ -120,13 +78,13 @@ def model_config(conf: Dict, *, phases: bool):
         conf["program"].get("preset", "full")]
     cfg = get(conf["program"]["arch"])
     for key in conf["reduced"]:
-        if key in _CUTS:
-            cfg = _CUTS[key](cfg, conf["config"][key])
+        if key in model.CUTS:
+            cfg = model.CUTS[key](cfg, conf["config"][key])
     model_r = conf["mesh"]["model"]
     if cfg.moe.num_experts % model_r:
         raise Refused(f"{cfg.moe.num_experts} experts do not divide over "
                       f"{model_r} chips")
-    bad = _mismatches(cfg, conf)
+    bad = _mismatches(cfg, conf, model.PROGRAM_KEYS)
     if bad:
         raise Refused("the program's config differs from "
                       f"{conf['name']}: " + "; ".join(bad))

@@ -1,46 +1,27 @@
-"""Plain float32 reference of the cut granite LSH-MoE train step.
+"""The plain float32 reference's architecture-free half: Adam, the step,
+the precisions, and the readings the check compares.
 
-Written from the configuration file and the paper, in straightforward
-``jax.numpy``: no kernels, no caches, and nothing imported from the
-program under test.  ``run`` spreads its weights and Adam moments over
-the cell's chips (each leaf split along one axis, ``spread``) so that a
-deep model fits; the arithmetic is the same on one chip or many.
-Matrix products run at ``jax.default_matmul_precision("highest")``;
-weights are stored in the type the configuration states (bf16, router
-in f32) and rounded to it after every update, as the program stores
-them, and the Adam moments are f32.  Centroids and expert outputs cross the LSH wire rounded to the
-wire type the configuration states (bf16).
-
-One step, for a batch [B, S] on ``groups`` chips sharing each layer:
-
-  embed * m_emb -> L x (rmsnorm -> GQA causal attention with RoPE,
-                        scores * m_att -> residual * m_res
-                        -> rmsnorm -> LSH-MoE -> residual * m_res)
-        -> rmsnorm -> LM head / logits_scaling
-        -> cross entropy + z-loss + router losses
-
-(the configuration's embedding, attention and residual multipliers and
-logits scaling; 1, head_dim^-0.5, 1 and 1 for a plain transformer).
-
-LSH-MoE, per chip group (the tokens a chip holds between blocks: every
-row of the batch, its 1/groups slice of the sequence):
-  softmax router, top-k, renormalised weights; capacity sized for the
-  group's own tokens, earlier (token, choice) entries first; the kept
-  tokens of each expert hashed by cross-polytope LSH (argmax |x R_l| with
-  the sign as the low bit, L hashes folded into one id, modulo the slot
-  count); one centroid per slot (the mean of its tokens); the expert MLP
-  on the centroids; each token gets its own input plus its slot's
-  expert delta E(c) - c (residual compensation); top-k weighted combine.
+A model module (``chipbench/models/<name>.py``, named by the
+configuration file's ``"model"``) gives the architecture: its weights
+and its loss, written from the configuration file and the published
+description in straightforward ``jax.numpy``: no kernels, no caches, and
+nothing imported from the program under test.  ``run`` spreads the
+weights and Adam moments over the cell's chips (each leaf split along
+one axis, ``spread``) so that a deep model fits; the arithmetic is the
+same on one chip or many.  Matrix products run at
+``jax.default_matmul_precision("highest")``; weights are stored in the
+type the configuration states (bf16, router in f32) and rounded to it
+after every update, as the program stores them, and the Adam moments are
+f32.
 
 ``precision="fp8"`` is the control, the reference computed a precision
 below the configuration's bf16: every activation it holds (the residual
 stream, each matrix product's operands and result) and each of their
 gradients is rounded to float8 e4m3 with one power-of-two scale per
-tensor, where the plain reference keeps float32.
+tensor, where the plain reference keeps float32 (``_held``, ``_mm``).
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Dict, NamedTuple
 
@@ -50,37 +31,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 F32 = jnp.float32
-FOLD_MULT = 1000003          # the paper's multi-hash fold: id = id * M + v_l
 E4M3_MAX = 448.0
-
-
-class Dims(NamedTuple):
-    """Sizes and settings the reference reads from the configuration."""
-    vocab: int
-    hidden: int
-    layers: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    experts: int
-    top_k: int
-    expert_ffn: int
-    rope_theta: float
-    norm_eps: float
-    capacity_factor: float
-    router_aux_weight: float
-    router_z_weight: float
-    z_loss_weight: float
-    lsh: bool
-    num_hashes: int
-    rotation_dim: int
-    compression_rate: float
-    error_compensation: bool
-    wire_dtype: str
-    embedding_multiplier: float = 1.0
-    attention_multiplier: float = 0.0      # 0: head_dim ** -0.5
-    residual_multiplier: float = 1.0
-    logits_scaling: float = 1.0
 
 
 class Adam(NamedTuple):
@@ -94,78 +45,12 @@ class Adam(NamedTuple):
     clip_norm: float
 
 
-def dims_from_config(c: Dict, *, use_lsh: bool) -> Dims:
-    """Dims from a configuration file's ``config`` and ``lsh`` objects."""
-    m, lsh = c["config"], c["lsh"]
-    return Dims(vocab=m["vocab_size"], hidden=m["hidden_size"],
-                layers=m["num_hidden_layers"],
-                heads=m["num_attention_heads"],
-                kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-                experts=m["num_local_experts"],
-                top_k=m["num_experts_per_tok"],
-                expert_ffn=m["intermediate_size"],
-                rope_theta=float(m["rope_theta"]),
-                norm_eps=float(m["rms_norm_eps"]),
-                capacity_factor=float(m["capacity_factor"]),
-                router_aux_weight=float(m["router_aux_loss_coef"]),
-                router_z_weight=float(m["router_z_loss_coef"]),
-                z_loss_weight=float(m["z_loss_coef"]),
-                lsh=use_lsh, num_hashes=lsh["num_hashes"],
-                rotation_dim=lsh["rotation_dim"],
-                compression_rate=float(lsh["compression_rate"]),
-                error_compensation=bool(lsh["error_compensation"]),
-                wire_dtype=lsh["wire_dtype"],
-                embedding_multiplier=float(m.get("embedding_multiplier",
-                                                 1.0)),
-                attention_multiplier=float(m.get("attention_multiplier",
-                                                 m["head_dim"] ** -0.5)),
-                residual_multiplier=float(m.get("residual_multiplier", 1.0)),
-                logits_scaling=float(m.get("logits_scaling", 1.0)))
-
-
 def adam_from_config(c: Dict) -> Adam:
     o = c["optimizer"]
     return Adam(lr=o["lr"], warmup_steps=o["warmup_steps"],
                 total_steps=o["total_steps"], b1=o["b1"], b2=o["b2"],
                 eps=o["eps"], weight_decay=o["weight_decay"],
                 clip_norm=o["clip_norm"])
-
-
-# ----------------------------------------------------------- weights ----
-
-def init_params(key, d: Dims) -> Dict:
-    """Seeded weights in the layout and types the train state holds:
-    bf16 matrices and norms, f32 router, bf16 LSH rotations, int32
-    expert placement.  Stacked leaves carry the layer axis first.
-    Matrices are N(0, 1/fan_in); the embedding N(0, 0.02^2); rotations
-    N(0, 1/hidden)."""
-    H, L, E, Fe = d.hidden, d.layers, d.experts, d.expert_ffn
-    nq, nkv = d.heads * d.head_dim, d.kv_heads * d.head_dim
-    bf = jnp.bfloat16
-    ks = iter(jax.random.split(key, 16))
-
-    def normal(shape, std, dtype=bf):
-        return (jax.random.normal(next(ks), shape, F32) * std).astype(dtype)
-
-    ffn = {"w_up": normal((L, E, H, Fe), H ** -0.5),
-           "w_down": normal((L, E, Fe, H), Fe ** -0.5),
-           "w_gate": normal((L, E, H, Fe), H ** -0.5),
-           "router_w": normal((L, H, E), H ** -0.5, F32),
-           "lsh_rot": normal((L, d.num_hashes, H, min(d.rotation_dim, H)),
-                             H ** -0.5),
-           "placement": jnp.broadcast_to(jnp.arange(E, dtype=jnp.int32),
-                                         (L, E))}
-    block = {"norm1": {"scale": jnp.ones((L, H), bf)},
-             "mixer": {"wq": normal((L, H, nq), H ** -0.5),
-                       "wk": normal((L, H, nkv), H ** -0.5),
-                       "wv": normal((L, H, nkv), H ** -0.5),
-                       "wo": normal((L, nq, H), nq ** -0.5)},
-             "norm2": {"scale": jnp.ones((L, H), bf)},
-             "ffn": ffn}
-    return {"embed": {"table": normal((d.vocab, H), 0.02)},
-            "final_norm": {"scale": jnp.ones((H,), bf)},
-            "head": {"w": normal((H, d.vocab), H ** -0.5)},
-            "blocks": [block]}
 
 
 # ----------------------------------------------------------- helpers ----
@@ -200,197 +85,6 @@ def _mm(spec: str, a, b, precision: str):
                  precision)
 
 
-def _rmsnorm(x, scale, eps):
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return x * scale.astype(F32)
-
-
-def _rope(x, theta):
-    """x: [B, S, n, dh]; rotate the first half against the second."""
-    dh, S = x.shape[-1], x.shape[1]
-    freqs = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=F32) / dh)
-    ang = jnp.arange(S, dtype=F32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _attention(p, h, d: Dims, precision):
-    B, S, _ = h.shape
-    dh, g = d.head_dim, d.heads // d.kv_heads
-    q = _mm("bsh,hd->bsd", h, p["wq"], precision).reshape(B, S, d.heads, dh)
-    k = _mm("bsh,hd->bsd", h, p["wk"], precision).reshape(B, S, d.kv_heads, dh)
-    v = _mm("bsh,hd->bsd", h, p["wv"], precision).reshape(B, S, d.kv_heads, dh)
-    q, k = _rope(q, d.rope_theta), _rope(k, d.rope_theta)
-    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    scale = d.attention_multiplier or dh ** -0.5
-
-    @jax.checkpoint
-    def one_row(qkv):                      # one batch row at a time
-        qr, kr, vr = qkv
-        s = _mm("qnd,knd->nqk", qr, kr, precision) * scale
-        s = jnp.where(causal[None], s, -jnp.inf)
-        return _mm("nqk,knd->qnd", jax.nn.softmax(s, axis=-1), vr, precision)
-
-    o = jax.lax.map(one_row, (q, k, v)).reshape(B, S, d.heads * dh)
-    return _mm("bsd,dh->bsh", o, p["wo"], precision)
-
-
-def _fold_hash(x, rot):
-    """Cross-polytope LSH ids of rows x [..., H] under rotations [L, H, Dr]."""
-    v = jnp.einsum("...h,lhr->...lr", jax.lax.stop_gradient(x),
-                   rot.astype(F32), precision=jax.lax.Precision.HIGHEST)
-    idx = jnp.argmax(jnp.abs(v), axis=-1)
-    neg = jnp.take_along_axis(v, idx[..., None], axis=-1)[..., 0] < 0
-    vertex = (2 * idx + neg).astype(jnp.int32)
-    out = jnp.zeros(vertex.shape[:-1], jnp.int32)
-    for l in range(vertex.shape[-1]):
-        out = out * jnp.int32(FOLD_MULT) + vertex[..., l]
-    return out
-
-
-def capacity(tokens: int, d: Dims) -> int:
-    """Buffer rows per expert for ``tokens`` routed tokens on one chip."""
-    cap = math.ceil(tokens * d.top_k / d.experts * d.capacity_factor)
-    return max(8, math.ceil(cap / 8) * 8)
-
-
-def lsh_slots(cap: int, d: Dims) -> int:
-    return max(8, math.ceil(cap * d.compression_rate / 8) * 8)
-
-
-def _wire(x, d: Dims):
-    """x as it crosses the wire: rounded to the configuration's wire type."""
-    return x.astype(jnp.dtype(d.wire_dtype)).astype(F32)
-
-
-def _expert_mlp(p, x, precision):
-    """x: [E, n, H] through each expert's SwiGLU MLP."""
-    up = _mm("enh,ehf->enf", x, p["w_up"], precision)
-    gate = _mm("enh,ehf->enf", x, p["w_gate"], precision)
-    return _mm("enf,efh->enh", jax.nn.silu(gate) * up, p["w_down"], precision)
-
-
-def _moe_group(p, x, d: Dims, precision):
-    """One chip's tokens x [T, H] -> (y [T, H], aux, z).  ``p`` holds every
-    expert, in the order the router numbers them."""
-    T, H = x.shape
-    E, k = d.experts, d.top_k
-    logits = _mm("th,he->te", x, p["router_w"], precision)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(probs, k)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
-    chosen = jax.nn.one_hot(ids, E, dtype=F32).sum(axis=1)
-    aux = E * jnp.sum(chosen.mean(0) * probs.mean(0))
-    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-
-    C = capacity(T, d)
-    flat = ids.reshape(T * k)
-    onehot = jax.nn.one_hot(flat, E, dtype=jnp.int32)
-    pos = jnp.sum(onehot * (jnp.cumsum(onehot, axis=0) - 1), axis=1)
-    keep = pos < C
-    row = jnp.where(keep, flat * C + pos, E * C)           # E * C: dropped
-    src = jnp.repeat(x, k, axis=0)
-    buf = jnp.zeros((E * C + 1, H), F32).at[row].add(src)[:E * C]
-    buf = buf.reshape(E, C, H)
-    filled = jnp.minimum(onehot.sum(0), C)                 # [E]
-    valid = jnp.arange(C)[None, :] < filled[:, None]       # [E, C]
-
-    if d.lsh:
-        S = lsh_slots(C, d)
-        slot = jnp.abs(_fold_hash(buf, p["lsh_rot"])) % jnp.int32(S)
-        slot = jnp.where(valid, slot, S)                   # S: no slot
-        seg = jax.nn.one_hot(slot, S, dtype=F32)           # [E, C, S]
-        count = seg.sum(axis=1)
-        cent = jnp.einsum("ecs,ech->esh", seg, buf,
-                          precision=jax.lax.Precision.HIGHEST)
-        cent = _wire(cent / jnp.maximum(count, 1.0)[..., None], d)
-        out = _wire(_expert_mlp(p, cent, precision), d)
-        if d.error_compensation:
-            delta = out - cent
-            res = buf + jnp.einsum("ecs,esh->ech", seg, delta,
-                                   precision=jax.lax.Precision.HIGHEST)
-        else:
-            res = jnp.einsum("ecs,esh->ech", seg, out,
-                             precision=jax.lax.Precision.HIGHEST)
-    else:
-        res = _expert_mlp(p, buf, precision)
-
-    got = jnp.concatenate([res.reshape(E * C, H), jnp.zeros((1, H), F32)])
-    y = (got[row] * w.reshape(T * k, 1)).reshape(T, k, H).sum(axis=1)
-    return y, aux, z
-
-
-def _moe(p, h, d: Dims, groups: int, precision, fault: str = ""):
-    """h [B, S, H]: each of ``groups`` chips holds every row's 1/groups
-    slice of the sequence and routes it on its own.
-
-    ``fault="no_exchange"`` plants the fault of an exchange left out: each
-    chip's buffers for all experts go through its own experts (expert e
-    through local expert e mod E/groups) instead of their owners'."""
-    B, S, H = h.shape
-    xs = h.reshape(B, groups, S // groups, H).transpose(1, 0, 2, 3)
-    local = d.experts // groups
-    owner = jnp.arange(d.experts) % local
-
-    def group(x, g):
-        q = p
-        if fault == "no_exchange":
-            q = dict(p, **{k: p[k][owner + g * local]
-                           for k in ("w_up", "w_gate", "w_down")})
-        return _moe_group(q, x.reshape(-1, H), d, precision)
-
-    y, aux, z = jax.vmap(group)(xs, jnp.arange(groups))
-    y = y.reshape(groups, B, S // groups, H).transpose(1, 0, 2, 3)
-    return y.reshape(B, S, H), aux.mean(), z.mean()
-
-
-def _layer(x, p, d: Dims, groups, precision, fault):
-    r = d.residual_multiplier
-    x = _held(x + r * _attention(p["mixer"],
-                                 _rmsnorm(x, p["norm1"]["scale"], d.norm_eps),
-                                 d, precision), precision)
-    y, aux, z = _moe(p["ffn"], _rmsnorm(x, p["norm2"]["scale"], d.norm_eps),
-                     d, groups, precision, fault)
-    return _held(x + r * y, precision), aux, z
-
-
-def loss_fn(params, tokens, labels, d: Dims, groups: int,
-            precision: str = "f32", fault: str = ""):
-    """Mean next-token cross entropy plus the z-loss and the router's
-    losses.  ``fault="half_batch"`` plants the fault of half the batch left
-    out: the loss is the mean over the first half of the rows."""
-    if fault == "half_batch":
-        half = tokens.shape[0] // 2
-        tokens, labels = tokens[:half], labels[:half]
-    x = _held(params["embed"]["table"].astype(F32)[tokens]
-              * d.embedding_multiplier, precision)
-    layer = jax.checkpoint(partial(_layer, d=d, groups=groups,
-                                   precision=precision, fault=fault))
-
-    def body(x, p):
-        x, aux, z = layer(x, p)
-        return x, (aux, z)
-
-    x, (aux, z) = jax.lax.scan(body, x, params["blocks"][0])
-    h = _rmsnorm(x, params["final_norm"]["scale"], d.norm_eps)
-
-    @jax.checkpoint
-    def row_loss(hl):                      # one batch row of the LM head
-        hr, lr = hl
-        logits = _mm("sh,hv->sv", hr, params["head"]["w"],
-                     precision) / d.logits_scaling
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, lr[:, None], axis=-1)[:, 0]
-        return jnp.stack([jnp.sum(lse - ll), jnp.sum(jnp.square(lse))])
-
-    sums = jax.lax.map(row_loss, (h, labels)).sum(axis=0)
-    n = labels.size
-    return (sums[0] / n + d.z_loss_weight * sums[1] / n
-            + d.router_aux_weight * aux.sum() + d.router_z_weight * z.sum())
-
-
 # --------------------------------------------------------------- step ---
 
 def _lr(step, o: Adam):
@@ -402,12 +96,12 @@ def _lr(step, o: Adam):
     return jnp.where(s < o.warmup_steps, warm, cos)
 
 
-def train_step(params, m, v, step, tokens, labels, d: Dims, o: Adam,
+def train_step(loss_fn, params, m, v, step, tokens, labels, d, o: Adam,
                groups: int, precision: str = "f32", fault: str = ""):
-    """AdamW with global-norm clipping and decoupled weight decay on every
-    float leaf.  Gradients are taken with respect to float32 copies of the
-    weights, and the updated weights are rounded back to their stored
-    type."""
+    """AdamW on the model module's ``loss_fn``, with global-norm clipping
+    and decoupled weight decay on every float leaf.  Gradients are taken
+    with respect to float32 copies of the weights, and the updated
+    weights are rounded back to their stored type."""
     wide = jax.tree.map(lambda p: p.astype(F32)
                         if jnp.issubdtype(p.dtype, jnp.floating) else p,
                         params)
@@ -495,27 +189,29 @@ def spread(shapes, devices):
     return jax.tree_util.tree_map_with_path(one, shapes)
 
 
-def run(key, batches, d: Dims, o: Adam, groups: int, steps: int = 3,
+def run(model, key, batches, d, o: Adam, groups: int, steps: int = 3,
         precision: str = "f32", fault: str = "",
         keep_moment: bool = False, devices=None) -> Dict:
-    """The reference's readings over the first ``steps`` steps from the
-    seeded weights: each step's loss, each float leaf's norm of the first
-    gradient as the optimiser takes it (clipped, recovered from the first
-    moment), and each float leaf's change over the ``steps`` steps.
+    """The reference's readings over the first ``steps`` steps of the
+    model module ``model`` (``d``: its ``dims``) from its seeded weights:
+    each step's loss, each float leaf's norm of the first gradient as the
+    optimiser takes it (clipped, recovered from the first moment), and
+    each float leaf's change over the ``steps`` steps.
     Weights and moments are spread over ``devices`` (default: the first
     device); batches are whole on each."""
     devices = list(devices or jax.devices()[:1])
     with jax.default_matmul_precision("highest"):
-        shapes = jax.eval_shape(partial(init_params, d=d), key)
+        shapes = jax.eval_shape(partial(model.init_params, d=d), key)
         p_sh = spread(shapes, devices)
         m_sh = spread(jax.eval_shape(zeros_moments, shapes), devices)
         whole = NamedSharding(Mesh(np.array(devices), ("x",)), P())
-        init = jax.jit(partial(init_params, d=d), out_shardings=p_sh)
+        init = jax.jit(partial(model.init_params, d=d), out_shardings=p_sh)
         params = init(key)
         m = jax.jit(zeros_moments, out_shardings=m_sh)(params)
         v = jax.jit(zeros_moments, out_shardings=m_sh)(params)
-        step = jax.jit(partial(train_step, d=d, o=o, groups=groups,
-                               precision=precision, fault=fault),
+        step = jax.jit(partial(train_step, model.loss_fn, d=d, o=o,
+                               groups=groups, precision=precision,
+                               fault=fault),
                        donate_argnums=(0, 1, 2),
                        out_shardings=(p_sh, m_sh, m_sh, whole))
         losses, grad, extra = [], None, {}

@@ -16,28 +16,29 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import program, reference
-from chipbench.tests.cells import DATA
+from chipbench import harness, program, reference
+from chipbench.tests.cells import BENCH, DATA
 from chipbench.traffic import Traffic
 
 
 def gaps(conf_name: str, mix_name: str, seed: int = 3) -> dict:
-    conf = json.loads((DATA / f"{conf_name}.json").read_text())
+    conf_path = DATA / f"{conf_name}.json"
+    conf = json.loads(conf_path.read_text())
     mix = json.loads((DATA / f"{mix_name}.json").read_text())
+    model = harness.model_module(conf, conf_path, BENCH / "models")
     program.import_program()
     from repro.models import model as model_lib
     use_lsh = mix["use_lsh"]
-    cfg = program.model_config(conf, phases=False)
+    cfg = program.model_config(conf, model, phases=False)
     lsh = dataclasses.replace(cfg.moe.lsh, wire_dtype="float32")
     cfg = cfg.replace(dtype="float32",
                       moe=dataclasses.replace(cfg.moe, lsh=lsh))
     mesh = program.mesh(conf)
-    d = reference.dims_from_config(conf, use_lsh=use_lsh)._replace(
-        wire_dtype="float32")
+    d = model.dims(conf, use_lsh=use_lsh)._replace(wire_dtype="float32")
     wide = jax.tree.map(
         lambda x: x.astype(jnp.float32)
         if jnp.issubdtype(x.dtype, jnp.floating) else x,
-        reference.init_params(jax.random.PRNGKey(seed), d))
+        model.init_params(jax.random.PRNGKey(seed), d))
     b = {k: jnp.asarray(v) for k, v in
          Traffic(mix, d.vocab, seed).batch_at(0).items()}
 
@@ -45,8 +46,8 @@ def gaps(conf_name: str, mix_name: str, seed: int = 3) -> dict:
         return model_lib.loss_fn(p, cfg, mesh, b, use_lsh=use_lsh)[0]
 
     def ref_loss(p):
-        return reference.loss_fn(p, b["tokens"], b["labels"], d,
-                                 conf["mesh"]["model"])
+        return model.loss_fn(p, b["tokens"], b["labels"], d,
+                             conf["mesh"]["model"])
 
     with jax.default_matmul_precision("highest"):
         with program.set_mesh(mesh):

@@ -1,6 +1,7 @@
 """Test cells on the CPU: a checkout-shaped directory with a
 ``BENCHMARK.json`` whose workloads use the small configurations under
-``tests/data``, and the benchmark's own metric readers."""
+``tests/data``, and the benchmark's own metric readers and model
+modules."""
 import json
 import shutil
 from pathlib import Path
@@ -16,8 +17,9 @@ def make_root(tmp: Path, cells, *, extra_per_layer=()) -> Path:
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     for sub in ("configs", "traffic"):
         (tmp / BENCH.name / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(BENCH / "metrics", tmp / BENCH.name / "metrics",
-                    dirs_exist_ok=True)
+    for sub in ("metrics", "models"):
+        shutil.copytree(BENCH / sub, tmp / BENCH.name / sub,
+                        dirs_exist_ok=True)
     workloads = []
     for name, conf, mix, chips in cells:
         shutil.copy(DATA / f"{conf}.json",

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from chipbench import counts
+from chipbench.models import granite_moe
 from chipbench.trace import Op
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
@@ -28,13 +29,13 @@ def test_model_flops_of_the_cut_granite(layers):
     m = json.loads((CONFIGS / "granite-moe-3b-a800m.ep4.json").read_text())[
         "config"]
     m = dict(m, num_hidden_layers=layers)
-    active = counts.active_matmul_params(m)
+    active = granite_moe.active_matmul_params(m)
     # attention 6.29M + router 0.06M + 8 experts 18.87M per layer, and the
     # 75.5M LM head: at 4 layers the ~1.75e8 of PERF.md
     assert active == layers * (6_291_456 + 61_440 + 18_874_368) + 75_502_080
     if layers == 4:
         assert abs(active / 1.75e8 - 1) < 0.01
-    per_token = counts.train_flops_per_token(m, 1024)
+    per_token = granite_moe.train_flops_per_token(m, 1024)
     assert per_token == 6 * active + 6 * layers * 24 * 64 * 1024
 
 

@@ -12,11 +12,8 @@ from chipbench.tests.cells import BENCH, DATA, REPO
 
 STEP_METRICS = ("attention_ms", "lm_head_ms", "optimizer_ms")
 NEW = ("moe_a2a_ms", "moe_a2a_bytes") + STEP_METRICS + ("unscoped_ms",)
-# Not listed in BENCHMARK.json: a listed metric that the parent program
-# cannot read fails the parent's traced run (``harness.Unread``), so a
-# benchmark change has to come first (PERF.md, open questions).
-NEW_ENTRIES = [{"name": n, "unit": "MB" if n.endswith("bytes") else "ms"}
-               for n in NEW]
+PER_LAYER = json.loads((REPO / "BENCHMARK.json").read_text())["per_layer"]
+NEW_ENTRIES = [m for m in PER_LAYER if m["name"] in NEW]
 WIRE = "bf16[4,10,104,1536]"             # one MoE leg's operand, 12.78 MB
 
 
@@ -124,17 +121,15 @@ PINNED4S = {
 def test_four_chip_trace_with_every_scope():
     """Five steps of ``granite.ep4.train-lsh`` on four v5e chips with the
     step-level and exchange scopes on, recorded by ``run.py --trace 1
-    --keep-trace``: every metric of the cell and each new reader reads,
-    as pinned;
+    --keep-trace``: every metric listed for the cell reads, as pinned;
     the MoE exchange is 24 all-to-alls a step (two legs, forward,
     rematerialised forward and backward, four layers) of 12.78 MB; and
     the scopes partition the step's busy time."""
     meta = json.loads((TRACE4S / "meta.json").read_text())
     with gzip.open(TRACE4S / "step.hlo.txt.gz", "rt") as f:
         tr = trace_lib.load(str(TRACE4S / "trace.xplane.pb.gz"), f.read())
-    per_layer = json.loads((REPO / "BENCHMARK.json").read_text())[
-        "per_layer"] + NEW_ENTRIES
-    got = harness.cell_metrics(per_layer, BENCH / "metrics",
+    assert [m["name"] for m in NEW_ENTRIES] == list(NEW)
+    got = harness.cell_metrics(PER_LAYER, BENCH / "metrics",
                                harness.trace_context(tr, meta))
     assert {k: v["value"] for k, v in got.items()} == pytest.approx(
         PINNED4S, rel=1e-9)
